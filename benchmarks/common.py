@@ -36,11 +36,25 @@ def mesh_for(num_shards: int):
     """A ``(1, num_shards)`` (data, model) mesh when the host presents
     enough devices (CI forces them via XLA_FLAGS), else ``None`` →
     single-device emulation.  Shared by every sharded-serving bench so
-    shard_map-vs-emulated selection can never diverge between them."""
+    shard_map-vs-emulated selection can never diverge between them.
+
+    On a TPU too few chips is an error: a bench that asked for shards
+    must not report emulation on one chip as a sharded run.
+    """
     import jax
 
-    if num_shards > 1 and len(jax.devices()) >= num_shards:
-        return jax.make_mesh((1, num_shards), ("data", "model"))
+    if num_shards <= 1:
+        return None
+    if len(jax.devices()) >= num_shards:
+        return jax.make_mesh(
+            (1, num_shards), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        )
+    if jax.devices()[0].platform == "tpu":
+        raise RuntimeError(
+            f"{num_shards} shards need {num_shards} TPU chips, "
+            f"found {len(jax.devices())}"
+        )
     return None
 
 

@@ -28,6 +28,7 @@ from benchmarks import (
     tier_bench,
 )
 from benchmarks.common import emit
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = {
     "fig8": fig8_speedup,
@@ -54,6 +55,7 @@ def main() -> None:
     ap.add_argument("--only", default=None, choices=list(MODULES))
     args = ap.parse_args()
 
+    enable_compile_cache()
     names = [args.only] if args.only else list(MODULES)
     print("name,us_per_call,derived")
     all_rows = []

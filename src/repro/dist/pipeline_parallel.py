@@ -20,12 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version shim
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def bubble_fraction(num_microbatches: int, num_stages: int) -> float:
     """Idle fraction of the ideal schedule: (S-1) / (M + S-1)."""
     if num_microbatches < 1 or num_stages < 1:
@@ -80,7 +74,7 @@ def pipelined_apply(
         mine = jnp.where(stage == num_stages - 1, outputs, jnp.zeros_like(outputs))
         return lax.psum(mine, "stage")
 
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("stage"), P()),

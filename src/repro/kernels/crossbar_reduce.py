@@ -9,27 +9,22 @@ TPU-native re-expression of the ReCross crossbar datapath (DESIGN.md §2):
   * the MAC path multiplies the wordline bitmap against the tile on the
     MXU (``bitmap @ tile``, a one-hot matmul — the in-memory MAC),
   * the READ path (popcount ≤ 1, ReCross §III-D) skips the MXU entirely
-    and dynamically slices the single active row out of VMEM — the
-    dynamic-switch ADC as a datapath branch,
+    and selects the single active row out of VMEM with a masked sum —
+    the dynamic-switch ADC as a datapath branch,
   * partial sums accumulate in a float32 VMEM scratch (the "ADC output
-    register"), written back once per query.
+    register"), written back once per query block.
 
-Two layouts (DESIGN.md §3):
-
-**Flat** — ``bitmaps (batch, max_tiles, tile_rows)``, grid
-``(batch, max_tiles)``: one query per grid row, one ``(1, tile_rows)``
-bitmap per tile DMA.
-
-**Query-blocked** — ``bitmaps (nb, max_tiles, q_block, tile_rows)`` with
-``tile_ids (nb, max_tiles)`` *shared by the whole block* (the host
+Layout (DESIGN.md §3): ``bitmaps (nb, max_tiles, q_block, tile_rows)``
+with ``tile_ids (nb, max_tiles)`` *shared by the whole block* (the host
 compiler deduplicates the block's tile set; correlated queries share hot
-tiles, so the union stays near one query's tile count).  Grid shrinks to
-``(batch // q_block, max_tiles)`` and the MAC becomes a
-``(q_block, tile_rows) @ (tile_rows, dim)`` matmul — one tile DMA is
-amortized over ``q_block`` queries and the MXU sees a real LHS instead of
-a single row.  The accumulator widens to a ``(q_block, dim)`` VMEM
-scratch (the multi-buffered "ADC output register": one live partial sum
-per in-flight query of the block), flushed once per block.
+tiles, so the union stays near one query's tile count).  Grid
+``(nb, max_tiles)``; the MAC is a ``(q_block, tile_rows) @ (tile_rows,
+dim)`` matmul — one tile DMA is amortized over ``q_block`` queries.  The
+accumulator is a ``(q_block, dim)`` VMEM scratch (one live partial sum
+per query of the block), flushed once per block.  A per-query batch
+``bitmaps (batch, max_tiles, tile_rows)`` runs as ``q_block=1``: its
+``(1, tile_rows)`` / ``(1, dim)`` trailing blocks span the full trailing
+dims, so they satisfy Mosaic's (8, 128) block rule.
 
 VMEM budget per grid step: one ``(tile_rows, dim)`` tile + one
 ``(q_block, dim)`` f32 accumulator + one ``(q_block, tile_rows)`` bitmap.
@@ -48,59 +43,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(
-    pad_ids_ref,    # scalar-prefetch: (batch, max_tiles) int32, -1 padding
-    safe_ids_ref,   # scalar-prefetch: ids clipped to >= 0 (feeds index_map)
-    bitmap_ref,     # VMEM (1, 1, tile_rows)
-    tile_ref,       # VMEM (1, tile_rows, dim) — the selected crossbar tile
-    out_ref,        # VMEM (1, dim)
-    acc_ref,        # scratch VMEM (1, dim) float32
-    *,
-    max_tiles: int,
-    dynamic_switch: bool,
-):
-    b = pl.program_id(0)
-    s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    bm = bitmap_ref[0, 0, :].astype(jnp.float32)          # (tile_rows,)
-    count = jnp.sum(bm)
-
-    def mac_path():
-        tile = tile_ref[0].astype(jnp.float32)            # (tile_rows, dim)
-        return jnp.dot(
-            bm.reshape(1, -1), tile, preferred_element_type=jnp.float32
-        )                                                  # (1, dim)
-
-    def read_path():
-        # single active wordline: pure row copy, no MXU issue
-        row = jnp.argmax(bm).astype(jnp.int32)
-        val = tile_ref[0, pl.ds(row, 1), :].astype(jnp.float32)  # (1, dim)
-        return val * (count > 0).astype(jnp.float32)
-
-    if dynamic_switch:
-        contrib = lax.cond(count <= 1.0, read_path, mac_path)
-    else:
-        contrib = mac_path()
-
-    # mask padding slots (tile_id < 0); their bitmaps are zero anyway, but
-    # the read path must not leak tile row 0 if a nonzero bitmap were paired
-    # with a padding id by a buggy caller.
-    valid = (pad_ids_ref[b, s] >= 0).astype(jnp.float32)
-    acc_ref[...] += contrib * valid
-
-    @pl.when(s == max_tiles - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
-
-
-def _blocked_kernel(
     pad_ids_ref,    # scalar-prefetch: (nb, max_tiles) int32, -1 padding
     safe_ids_ref,   # scalar-prefetch: ids clipped to >= 0 (feeds index_map)
     bitmap_ref,     # VMEM (1, 1, q_block, tile_rows)
@@ -119,21 +63,34 @@ def _blocked_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     bm = bitmap_ref[0, 0].astype(jnp.float32)         # (q_block, tile_rows)
-    q_block, tile_rows = bm.shape
+    q_block = bm.shape[0]
     count = jnp.sum(bm)
+    # at default precision the MXU rounds f32 operands to bf16 (measured on
+    # v5e: max error 2.5e-2 on a 64-row dot of N(0, 1) values); a bf16 tile
+    # and the 0/1 bitmap survive that rounding exactly, an f32 table does not
+    precision = (
+        lax.Precision.HIGHEST if tile_ref.dtype == jnp.float32 else None
+    )
 
     def mac_path():
         tile = tile_ref[0].astype(jnp.float32)        # (tile_rows, dim)
-        return jnp.dot(bm, tile, preferred_element_type=jnp.float32)
+        return jnp.dot(
+            bm, tile, precision=precision, preferred_element_type=jnp.float32
+        )
 
     def read_path():
         # exactly one active wordline in the whole block: copy that row
-        # into the single active query's accumulator lane, no MXU issue
-        flat = bm.reshape(-1)
-        idx = jnp.argmax(flat).astype(jnp.int32)
-        row = jnp.remainder(idx, tile_rows)
-        q = idx // tile_rows
-        val = tile_ref[0, pl.ds(row, 1), :].astype(jnp.float32)   # (1, dim)
+        # into the single active query's accumulator lane, no MXU issue.
+        # 2-D index sums (exact for a single set bit) and a masked row
+        # select keep every access tile-aligned, which Mosaic requires
+        hot = bm > 0
+        row = jnp.sum(jnp.where(hot, lax.broadcasted_iota(jnp.int32, bm.shape, 1), 0))
+        q = jnp.sum(jnp.where(hot, lax.broadcasted_iota(jnp.int32, bm.shape, 0), 0))
+        tile = tile_ref[0].astype(jnp.float32)        # (tile_rows, dim)
+        rows = lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+        val = jnp.sum(
+            jnp.where(rows == row, tile, 0.0), axis=0, keepdims=True
+        )                                             # (1, dim)
         lane = (
             lax.broadcasted_iota(jnp.int32, (q_block, 1), 0) == q
         ).astype(jnp.float32)
@@ -155,7 +112,7 @@ def _blocked_kernel(
 def crossbar_reduce_pallas(
     image: jax.Array,     # (num_tiles, tile_rows, dim)
     tile_ids: jax.Array,  # (batch | nb, max_tiles) int32, -1 padding
-    bitmaps: jax.Array,   # flat (batch, max_tiles, tile_rows)
+    bitmaps: jax.Array,   # per query (batch, max_tiles, tile_rows)
                           # or blocked (nb, max_tiles, q_block, tile_rows)
     *,
     dynamic_switch: bool = True,
@@ -163,26 +120,27 @@ def crossbar_reduce_pallas(
 ) -> jax.Array:
     """Raw pallas_call wrapper (no custom_vjp; see ops.crossbar_reduce).
 
-    Dispatches on the bitmap rank: 3-D bitmaps run the flat one-query-per-
-    grid-row kernel; 4-D bitmaps run the query-blocked kernel (``q_block``
-    queries share each tile DMA; see ``repro.core.reduction.
-    block_compiled_queries`` for the host-side block compiler).  The
-    blocked form returns ``(nb * q_block, dim)`` — block-major query
-    order, matching the flat batch order the block compiler consumed.
+    4-D bitmaps run the query-blocked kernel (``q_block`` queries share
+    each tile DMA; see ``repro.core.reduction.block_compiled_queries``
+    for the host-side block compiler) and return ``(nb * q_block, dim)``
+    in block-major query order, matching the flat batch order the block
+    compiler consumed.  3-D per-query bitmaps run the same kernel as
+    ``q_block=1`` and return ``(batch, dim)``.
     """
     num_tiles, tile_rows, dim = image.shape
     batch, max_tiles = tile_ids.shape
-    if bitmaps.ndim == 4:
-        nb, s_blk, q_block, r = bitmaps.shape
-        if (nb, s_blk, r) != (batch, max_tiles, tile_rows):
-            raise ValueError(
-                f"blocked bitmaps {bitmaps.shape} inconsistent with "
-                f"tile_ids {tile_ids.shape} / tile_rows {tile_rows}"
-            )
-    elif bitmaps.shape != (batch, max_tiles, tile_rows):
-        raise ValueError(f"bitmaps shape {bitmaps.shape} inconsistent")
-    else:
-        q_block = None
+    if bitmaps.ndim == 3:
+        if bitmaps.shape != (batch, max_tiles, tile_rows):
+            raise ValueError(f"bitmaps shape {bitmaps.shape} inconsistent")
+        bitmaps = bitmaps[:, :, None, :]
+    elif bitmaps.ndim != 4 or (
+        bitmaps.shape[:2] + bitmaps.shape[3:] != (batch, max_tiles, tile_rows)
+    ):
+        raise ValueError(
+            f"blocked bitmaps {bitmaps.shape} inconsistent with "
+            f"tile_ids {tile_ids.shape} / tile_rows {tile_rows}"
+        )
+    q_block = bitmaps.shape[2]
     if dim % 128 != 0:
         raise ValueError(f"dim={dim} must be a multiple of 128 (MXU lanes)")
     if tile_rows % 8 != 0:
@@ -194,50 +152,29 @@ def crossbar_reduce_pallas(
     safe_ids = jnp.maximum(tile_ids, 0).astype(jnp.int32)
     padded_ids = tile_ids.astype(jnp.int32)
 
-    if q_block is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # padded_ids (mask), safe_ids (index map)
-            grid=(batch, max_tiles),
-            in_specs=[
-                pl.BlockSpec((1, 1, tile_rows), lambda b, s, pad, safe: (b, s, 0)),
-                pl.BlockSpec((1, tile_rows, dim), lambda b, s, pad, safe: (safe[b, s], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, dim), lambda b, s, pad, safe: (b, 0)),
-            scratch_shapes=[pltpu.VMEM((1, dim), jnp.float32)],
-        )
-        kernel = functools.partial(
-            _kernel, max_tiles=max_tiles, dynamic_switch=dynamic_switch
-        )
-        out_shape = jax.ShapeDtypeStruct((batch, dim), image.dtype)
-    else:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(batch, max_tiles),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, 1, q_block, tile_rows), lambda n, s, pad, safe: (n, s, 0, 0)
-                ),
-                pl.BlockSpec(
-                    (1, tile_rows, dim), lambda n, s, pad, safe: (safe[n, s], 0, 0)
-                ),
-            ],
-            out_specs=pl.BlockSpec((1, q_block, dim), lambda n, s, pad, safe: (n, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((q_block, dim), jnp.float32)],
-        )
-        kernel = functools.partial(
-            _blocked_kernel, max_tiles=max_tiles, dynamic_switch=dynamic_switch
-        )
-        out_shape = jax.ShapeDtypeStruct((batch, q_block, dim), image.dtype)
-
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # padded_ids (mask), safe_ids (index map)
+        grid=(batch, max_tiles),
+        in_specs=[
+            pl.BlockSpec(
+                (1, 1, q_block, tile_rows), lambda n, s, pad, safe: (n, s, 0, 0)
+            ),
+            pl.BlockSpec(
+                (1, tile_rows, dim), lambda n, s, pad, safe: (safe[n, s], 0, 0)
+            ),
+        ],
+        out_specs=pl.BlockSpec((1, q_block, dim), lambda n, s, pad, safe: (n, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((q_block, dim), jnp.float32)],
+    )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(
+            _kernel, max_tiles=max_tiles, dynamic_switch=dynamic_switch
+        ),
         grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((batch, q_block, dim), image.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(padded_ids, safe_ids, bitmaps, image)
-    if q_block is not None:
-        out = out.reshape(batch * q_block, dim)
-    return out
+    return out.reshape(batch * q_block, dim)

@@ -37,8 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(
     len_ref,       # scalar prefetch: (1,) int32 valid length
@@ -147,7 +145,7 @@ def fused_decode_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(
     pad_idx_ref,   # scalar-prefetch (batch, bag) int32 row ids, -1 pad
@@ -93,7 +91,7 @@ def embedding_bag_pallas(
         functools.partial(_kernel, bag=bag),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, dim), table.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

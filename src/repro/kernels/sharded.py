@@ -49,15 +49,6 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels.crossbar_reduce import crossbar_reduce_pallas
 
 
-def _shard_map():
-    try:
-        return jax.shard_map
-    except AttributeError:  # jax < 0.5
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
 # Bound on each jit-dispatch cache below.  The caches are keyed on the
 # participants tuple (plus static knobs), and an adversarial mix of
 # owner-set flush shapes can mint a fresh participants tuple per flush —
@@ -126,14 +117,14 @@ def _mesh_fn(mesh, axis_name, chunks, dynamic_switch, interpret, scatter):
             out = lax.all_gather(out, axis_name, axis=1, tiled=True)
         return out[None]
 
-    return jax.jit(_shard_map()(
+    return jax.jit(jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=P(axis_name),
-        # pallas_call has no replication rule; replication is
+        # pallas_call has no varying-axes rule; replication is
         # re-established explicitly by the psum/all_gather combine
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -169,12 +160,12 @@ def _mesh_subset_fn(mesh, axis_name, chunks, dynamic_switch, interpret,
             ))
         return jnp.concatenate(outs, axis=0)[None]
 
-    return jax.jit(_shard_map()(
+    return jax.jit(jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=P(axis_name),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -196,12 +187,12 @@ def _mesh_single_fn(mesh, axis_name, chunks, dynamic_switch, interpret):
         ]
         return jnp.concatenate(parts, axis=0)[None]
 
-    return jax.jit(_shard_map()(
+    return jax.jit(jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=P(axis_name),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -300,6 +291,37 @@ def crossbar_reduce_sharded(
       ``(nb * q_block, dim)`` summed reduction in block-major query
       order — the same contract as ``crossbar_reduce_blocked``.
     """
+    fn, args, take = _select_dispatch(
+        images, tile_ids, bitmaps, mesh=mesh, axis_name=axis_name,
+        combine=combine, combine_chunks=combine_chunks,
+        dynamic_switch=dynamic_switch, interpret=interpret,
+        shard_ids=shard_ids,
+    )
+    out = fn(*args)
+    return out if take is None else out[take].astype(images.dtype)
+
+
+def lower_sharded(images, tile_ids, bitmaps, **kw):
+    """Lowers, without running, the program :func:`crossbar_reduce_sharded`
+    would dispatch for these arguments (same keywords).  Its text shows
+    whether the kernel is a compiled Mosaic call (``tpu_custom_call``)
+    or runs in interpret mode."""
+    fn, args, _ = _select_dispatch(images, tile_ids, bitmaps, **kw)
+    return fn.lower(*args)
+
+
+def _select_dispatch(
+    images, tile_ids, bitmaps, *, mesh=None, axis_name="model",
+    combine="psum_scatter", combine_chunks=1, dynamic_switch=True,
+    interpret=None, shard_ids=None,
+):
+    """Validates a sharded reduction and picks its cached jit program.
+
+    Returns ``(fn, args, take)``: the program, its arguments (subset
+    schedules already scattered into the full mesh stack) and the
+    stacked row of its output that holds the result (``None`` when the
+    output is the result, as on the emulation path).
+    """
     S, _, _, dim = images.shape
     if shard_ids is None:
         if tile_ids.shape[0] != S or bitmaps.shape[0] != S:
@@ -327,7 +349,7 @@ def crossbar_reduce_sharded(
         fn = _emulated_fn(
             tuple(part.tolist()), combine_chunks, dynamic_switch, interpret
         )
-        return fn(images, tile_ids, bitmaps)
+        return fn, (images, tile_ids, bitmaps), None
 
     mesh_axis = dict(zip(mesh.axis_names, mesh.devices.shape)).get(axis_name)
     if mesh_axis != S:
@@ -355,8 +377,7 @@ def crossbar_reduce_sharded(
         fn = _mesh_single_fn(
             mesh, axis_name, combine_chunks, dynamic_switch, interpret
         )
-        out = fn(images, tile_ids, bitmaps)
-        return out[int(part[0])].astype(images.dtype)
+        return fn, (images, tile_ids, bitmaps), int(part[0])
 
     P = int(part.size)
     if P < S and S % P == 0:
@@ -379,16 +400,14 @@ def crossbar_reduce_sharded(
             mesh, axis_name, combine_chunks, dynamic_switch, interpret,
             groups,
         )
-        out = fn(images, tile_ids, bitmaps)
-        return out[int(part[0])].astype(images.dtype)
+        return fn, (images, tile_ids, bitmaps), int(part[0])
 
     scatter = combine == "psum_scatter" and dim % S == 0
     fn = _mesh_fn(
         mesh, axis_name, combine_chunks, dynamic_switch, interpret, scatter
     )
-    out = fn(images, tile_ids, bitmaps)
     # every shard returns the full combined batch; take shard 0's copy
-    return out[0].astype(images.dtype)
+    return fn, (images, tile_ids, bitmaps), 0
 
 
 def crossbar_reduce_tables(

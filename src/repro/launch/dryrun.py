@@ -510,12 +510,7 @@ def run_dlrm_cell(*, multi_pod: bool, results_dir: str = RESULTS_DIR, force=Fals
             take = table_loc[jnp.clip(rel, 0, r_loc - 1)] * ok[..., None].astype(table_loc.dtype)
             return jax.lax.psum(take.sum(axis=1), "model")
 
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:  # jax < 0.5
-            from jax.experimental.shard_map import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P("model", None), P(dp, None)),
             out_specs=P(dp, None),

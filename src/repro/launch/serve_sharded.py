@@ -1,7 +1,8 @@
-"""Sharded multi-table serving launcher (real shard_map on host devices).
+"""Sharded multi-table serving launcher (real shard_map over the devices).
 
-Forces the host platform to present enough devices, builds a
-``(1, num_shards)`` (data, model) mesh, stands up a
+Builds a ``(1, num_shards)`` (data, model) mesh over the visible devices
+(on the CPU platform, ``JAX_PLATFORMS=cpu``, it first forces the host to
+present ``--shards`` devices), stands up a
 :class:`~repro.serve.sharded.ShardedEmbeddingServer` over synthetic
 Zipf-weighted tables, and drives a continuous stream of per-table
 queries through the batched flush path.  Prints the per-shard grid
@@ -9,7 +10,8 @@ cells / combine bytes / wall time report.
 
 Usage::
 
-    PYTHONPATH=src python -m repro.launch.serve_sharded --shards 4 --tables 2
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve_sharded \
+        --shards 4 --tables 2                 # 4 forced host devices
     PYTHONPATH=src python -m repro.launch.serve_sharded --emulate   # no mesh
     PYTHONPATH=src python -m repro.launch.serve_sharded --emulate --drift
     PYTHONPATH=src python -m repro.launch.serve_sharded --emulate \
@@ -45,6 +47,8 @@ DMA'd, residual drift).
 The module is import-safe: args are parsed and ``XLA_FLAGS`` is set only
 when run as ``__main__`` (the device-count flag must land before the
 first jax import, so :func:`main` defers its jax-touching imports).
+:func:`main` keeps compiled programs in the persistent compilation cache
+(:mod:`repro.launch.compile_cache`).
 """
 
 from __future__ import annotations
@@ -216,8 +220,10 @@ def main(args) -> None:
     import jax
 
     from repro.data import zipf_queries
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve.sharded import ShardedEmbeddingServer
 
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     tables = {
         f"t{i}": rng.normal(size=(args.rows, args.dim)).astype(np.float32)
@@ -230,12 +236,22 @@ def main(args) -> None:
 
     mesh = None
     if not args.emulate:
-        if len(jax.devices()) < args.shards:
-            raise SystemExit(
-                f"only {len(jax.devices())} devices visible, need {args.shards} "
-                "(XLA_FLAGS forcing failed?)"
+        devices = jax.devices()
+        if len(devices) < args.shards:
+            hint = (
+                "run with JAX_PLATFORMS=cpu to force host devices"
+                if devices[0].platform == "cpu" else
+                f"this host has {len(devices)} {devices[0].platform} "
+                f"device(s)"
             )
-        mesh = jax.make_mesh((1, args.shards), ("data", "model"))
+            raise SystemExit(
+                f"--shards {args.shards} needs {args.shards} devices, "
+                f"found {len(devices)}: {hint}, or pass --emulate"
+            )
+        mesh = jax.make_mesh(
+            (1, args.shards), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        )
 
     replan_cfg = None
     if args.drift:
@@ -363,7 +379,7 @@ def main(args) -> None:
 
 if __name__ == "__main__":
     _args = parse_args()
-    if not _args.emulate:
+    if not _args.emulate and "cpu" in os.environ.get("JAX_PLATFORMS", ""):
         # must precede the first jax import (inside main)
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={max(_args.shards, 1)} "

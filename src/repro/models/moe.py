@@ -166,7 +166,6 @@ def apply_moe_shardmap(
     dp = rules.get("batch", "data")
     dp_axes = dp if isinstance(dp, tuple) else (dp,)
     manual = frozenset(dp_axes)
-    auto = frozenset(mesh.axis_names) - manual
 
     def local(x_loc, router, w_gate, w_val, w_out):
         # gather the FSDP (data-dim) shards of the expert weights
@@ -177,19 +176,6 @@ def apply_moe_shardmap(
         y_loc, aux = apply_moe(pl, x_loc, moe, act, shard_buffers=False)
         return y_loc, jax.lax.pmean(aux, dp_axes[-1])
 
-    try:
-        shard_map = jax.shard_map
-        partial_kw = {"axis_names": manual}
-    except AttributeError:
-        # jax < 0.5 only has the experimental API (param spelled `auto`),
-        # and its partial-auto regions hard-abort XLA-CPU's SPMD
-        # partitioner when the manual body issues collectives
-        # (spmd_partitioner.cc IsManualSubgroup check, verified on 0.4.37).
-        # Fall back to the GSPMD auto path rather than risk a process
-        # abort — slower (buffer-sized all-reduces) but correct.
-        del auto
-        return apply_moe(p, x, moe, act)
-
     in_specs = (
         P(dp, None, None),        # x: batch over dp
         P(),                      # router replicated
@@ -198,9 +184,9 @@ def apply_moe_shardmap(
         P(None, None, dp),        # w_out (E, f, d/fsdp)
     )
     out_specs = (P(dp, None, None), P())
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **partial_kw,
+        axis_names=manual,
     )(x, p["router"], p["w_gate"], p["w_val"], p["w_out"])
     return y, aux
 

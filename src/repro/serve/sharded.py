@@ -111,6 +111,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import (
     build_cooccurrence,
@@ -134,6 +135,7 @@ from repro.kernels.sharded import (
     combine_bytes_per_batch,
     crossbar_reduce_tables,
     dispatch_cache_stats,
+    lower_sharded,
     patch_shard_images,
 )
 from repro.serve.drift import DriftTracker, LoadObservationCache, ReplanConfig
@@ -555,7 +557,7 @@ class ShardedEmbeddingServer:
                 dtype=images.dtype,
             )
             images = np.concatenate([images, pad], axis=1)
-        self.shard_images = jnp.asarray(images)
+        self.shard_images = self._place_images(images)
         #: host→device bytes of one fused tile — the paging_bytes unit
         self._tile_bytes = int(self._fused[0].nbytes) if len(self._fused) else 0
         self._tile_group = np.repeat(
@@ -841,6 +843,32 @@ class ShardedEmbeddingServer:
             )
         return out
 
+    def _place_images(self, images) -> jax.Array:
+        """Puts the ``(S, tiles, rows, dim)`` image stack on the device(s)
+        once: with a mesh, shard ``i``'s slice lives on the device at
+        mesh position ``i`` of ``axis_name``, so no flush reshards the
+        table from one chip."""
+        if self.mesh is None:
+            return jnp.asarray(images)
+        return jax.device_put(
+            images, NamedSharding(self.mesh, PartitionSpec(self.axis_name))
+        )
+
+    def lower_flush(self, queries_by_table, participants=None):
+        """Lowers, without running, the program one flush of these
+        queries dispatches (``participants=`` as the async homes compile
+        a shard subset).  ``.as_text()`` of the result shows whether the
+        crossbar kernel is a compiled Mosaic call (``tpu_custom_call``)."""
+        served = [n for n in self.names if queries_by_table.get(n)]
+        _, sbq, _ = self._compile_batch(served, queries_by_table, participants)
+        return lower_sharded(
+            self.shard_images, sbq.tile_ids, sbq.bitmaps,
+            mesh=self.mesh, axis_name=self.axis_name, combine=self.combine,
+            combine_chunks=self.combine_chunks,
+            dynamic_switch=self.dynamic_switch, interpret=self.interpret,
+            shard_ids=sbq.shards,
+        )
+
     def _compile_batch(self, served, queries_of, participants=None):
         """Fused host compile shared by the sync and async paths.
 
@@ -913,9 +941,9 @@ class ShardedEmbeddingServer:
                 return
         patch, self._staged = self._staged, None
         self._patch_fail_streak = 0
-        self.shard_images = patch_shard_images(
+        self.shard_images = self._place_images(patch_shard_images(
             self.shard_images, patch, self._fused
-        )
+        ))
         self.plan = apply_plan_patch(self.plan, patch)
         self.stats.record_patch(patch, tile_bytes=self._tile_bytes)
         if self._residency is not None:

@@ -631,7 +631,8 @@ sbq2 = shard_block_queries(cq, sp2, 4)
 sbqf = shard_block_queries(cq, fresh, 4)
 emu = np.asarray(crossbar_reduce_sharded(images2, sbq2.tile_ids, sbq2.bitmaps,
                                          combine_chunks=2))
-mesh = jax.make_mesh((1, S), ("data", "model"))
+mesh = jax.make_mesh((1, S), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 for combine in ("psum_scatter", "psum"):
     sm = np.asarray(crossbar_reduce_sharded(
         images2, sbq2.tile_ids, sbq2.bitmaps, mesh=mesh,

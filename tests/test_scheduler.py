@@ -931,7 +931,8 @@ histories = {{"a": zipf_queries(rows, 32, 5.0, seed=1)}}
 stream = zipf_queries(rows, 30, 5.0, seed=2)
 perm = np.random.default_rng(4).permutation(rows)
 stream = stream[:10] + [perm[np.asarray(q, np.int64)] for q in stream[10:]]
-mesh = jax.make_mesh((1, S), ("data", "model"))
+mesh = jax.make_mesh((1, S), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 def run(policy, mesh, **kw):
     srv = ShardedEmbeddingServer(
@@ -997,7 +998,8 @@ rows, dim, S = 96, 128, 4
 tables = {{"a": np.random.default_rng(3).integers(
     -8, 9, size=(rows, dim)).astype(np.float32)}}
 histories = {{"a": zipf_queries(rows, 32, 5.0, seed=1)}}
-mesh = jax.make_mesh((1, S), ("data", "model"))
+mesh = jax.make_mesh((1, S), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 # owner map for crafting 2-owner queries (read off a probe server)
 probe = ShardedEmbeddingServer(

@@ -244,7 +244,7 @@ def test_per_shard_grid_never_exceeds_single_device_grid():
 
 def test_shard_map_branch_matches_emulation_subprocess():
     """The REAL shard_map branch (psum_scatter + all_gather, psum
-    fallback, check_rep=False, out[0] selection) must be bit-identical
+    fallback, check_vma=False, out[0] selection) must be bit-identical
     to the emulation path.  Device forcing must precede jax init, so the
     parity check runs in a subprocess with 2 forced host devices."""
     import os
@@ -279,7 +279,8 @@ sbq = shard_block_queries(cq, sp, 4)
 images = jnp.asarray(sp.build_shard_images(fused))
 emu = np.asarray(crossbar_reduce_sharded(images, sbq.tile_ids, sbq.bitmaps,
                                          combine_chunks=2))
-mesh = jax.make_mesh((1, S), ("data", "model"))
+mesh = jax.make_mesh((1, S), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 for combine in ("psum_scatter", "psum"):
     sm = np.asarray(crossbar_reduce_sharded(
         images, sbq.tile_ids, sbq.bitmaps, mesh=mesh,
@@ -300,6 +301,23 @@ print("SHARD_MAP_PARITY_OK")
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "SHARD_MAP_PARITY_OK" in proc.stdout
+
+
+def test_bench_mesh_refuses_one_chip_emulation_on_tpu(monkeypatch):
+    """A sharded bench on too few TPU chips must fail, not silently
+    report single-device emulation as a sharded run; on the CPU it
+    still falls back to emulation."""
+    from benchmarks.common import mesh_for
+
+    assert mesh_for(2) is None  # one CPU device: emulation
+
+    class Chip:
+        platform = "tpu"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [Chip()])
+    with pytest.raises(RuntimeError, match="need 4 TPU chips"):
+        mesh_for(4)
+    assert mesh_for(1) is None
 
 
 # ------------------------------------------------------- multi-table --
