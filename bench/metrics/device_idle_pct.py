@@ -1,0 +1,12 @@
+"""Device: the share of the traced window in which no operation ran on
+the chip (1 - union of device op intervals / window), in percent."""
+
+UNIT = "%"
+LAYER = "device"
+MOVES = "bags_per_s"
+
+
+def read(m):
+    if m.trace is None or m.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - m.trace.busy_s / m.trace.window_s)
