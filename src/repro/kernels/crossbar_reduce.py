@@ -176,5 +176,6 @@ def crossbar_reduce_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="recross_crossbar_reduce",
     )(padded_ids, safe_ids, bitmaps, image)
     return out.reshape(batch * q_block, dim)

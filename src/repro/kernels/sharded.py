@@ -30,7 +30,10 @@ wrappers (DESIGN.md §7.2): the serving loop re-invokes one flush shape
 over and over, so repeat flushes skip retracing and — crucially for the
 async engine — a dispatch returns immediately with the computation
 executing asynchronously, which is what the double-buffered
-host-compile / device-execute overlap overlaps with.
+host-compile / device-execute overlap overlaps with.  Every wrapped
+function is named ``recross_flush`` and every Pallas call
+``recross_crossbar_reduce``, so a device trace shows the flush program
+and its kernel by name.
 
 This is inference-path machinery: no custom VJP (training through the
 sharded image goes through the single-shard ``crossbar_reduce`` entries).
@@ -71,7 +74,7 @@ def _emulated_fn(shards, chunks, dynamic_switch, interpret):
     device-execute overlap would have nothing to overlap with off-TPU.
     """
 
-    def fn(images, tile_ids, bitmaps):
+    def recross_flush(images, tile_ids, bitmaps):
         nb, q_block = bitmaps.shape[1], bitmaps.shape[3]
         dim = images.shape[-1]
         bounds = _chunk_bounds(nb, chunks)
@@ -87,14 +90,14 @@ def _emulated_fn(shards, chunks, dynamic_switch, interpret):
             out = out + jnp.concatenate(parts, axis=0)
         return out.astype(images.dtype)
 
-    return jax.jit(fn)
+    return jax.jit(recross_flush)
 
 
 @functools.lru_cache(maxsize=DISPATCH_CACHE_MAXSIZE)
 def _mesh_fn(mesh, axis_name, chunks, dynamic_switch, interpret, scatter):
     """jit-cached shard_map reduction (full-axis combine)."""
 
-    def local(img, ids, bms):
+    def recross_flush(img, ids, bms):
         img, ids, bms = img[0], ids[0], bms[0]
         bounds = _chunk_bounds(ids.shape[0], chunks)
         outs = []
@@ -118,7 +121,7 @@ def _mesh_fn(mesh, axis_name, chunks, dynamic_switch, interpret, scatter):
         return out[None]
 
     return jax.jit(jax.shard_map(
-        local,
+        recross_flush,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=P(axis_name),
@@ -146,7 +149,7 @@ def _mesh_subset_fn(mesh, axis_name, chunks, dynamic_switch, interpret,
 
     index_groups = [list(g) for g in groups]
 
-    def local(img, ids, bms):
+    def recross_flush(img, ids, bms):
         img, ids, bms = img[0], ids[0], bms[0]
         bounds = _chunk_bounds(ids.shape[0], chunks)
         outs = []
@@ -161,7 +164,7 @@ def _mesh_subset_fn(mesh, axis_name, chunks, dynamic_switch, interpret,
         return jnp.concatenate(outs, axis=0)[None]
 
     return jax.jit(jax.shard_map(
-        local,
+        recross_flush,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=P(axis_name),
@@ -175,7 +178,7 @@ def _mesh_single_fn(mesh, axis_name, chunks, dynamic_switch, interpret):
     single-participant flush path (the participant's stacked output is
     the result; non-participants run empty masked grids)."""
 
-    def local(img, ids, bms):
+    def recross_flush(img, ids, bms):
         img, ids, bms = img[0], ids[0], bms[0]
         bounds = _chunk_bounds(ids.shape[0], chunks)
         parts = [
@@ -188,7 +191,7 @@ def _mesh_single_fn(mesh, axis_name, chunks, dynamic_switch, interpret):
         return jnp.concatenate(parts, axis=0)[None]
 
     return jax.jit(jax.shard_map(
-        local,
+        recross_flush,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
         out_specs=P(axis_name),

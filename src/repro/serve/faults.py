@@ -87,7 +87,7 @@ KINDS = ("compile", "device", "device-late", "hang", "poison", "patch")
 
 def latency_percentiles(samples: Sequence[float]) -> Dict[str, float]:
     """p50/p95/p99 of a latency sample list (seconds; zeros when empty)."""
-    if not samples:
+    if len(samples) == 0:
         return {"p50": 0.0, "p95": 0.0, "p99": 0.0}
     a = np.asarray(samples, dtype=np.float64)
     return {

@@ -111,6 +111,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import (
@@ -148,6 +149,7 @@ from repro.serve.faults import (
 )
 from repro.serve.producers import ProducerRegistry
 from repro.serve.scheduler import POOL, FlushPolicy, FlushScheduler
+from repro.serve.stamps import CompletionStamps, StampRecords
 from repro.serve.tiers import HostFetchQueue, ResidencyIndex, TierConfig
 
 
@@ -170,6 +172,7 @@ class _InFlight:
     participants: Optional[List[int]] = None
     t_dispatch: float = 0.0                # kernel dispatch (perf_counter)
     hang_s: Optional[float] = None         # injected hang (None = healthy)
+    flush: int = 0                         # dispatch sequence number
 
 
 #: bound of the driver-failure stash (first-in surfaces first; overflow
@@ -182,13 +185,22 @@ class ShardedServeStats:
     """Accumulated per-flush accounting of the sharded datapath.
 
     Under an async flush policy (DESIGN.md §7) ``wall_s`` is the sum of
-    per-flush dispatch→retire latencies, which OVERLAP — end-to-end wall
-    clock is what the scheduler bench measures; the pipelining gain
-    shows up here as ``hidden_compile_s`` (host compile time that ran
-    while a previous flush executed on device) over ``host_compile_s``.
-    Latency samples are kept raw (one float per flush / per submit) so
-    ``summary()`` can report percentiles; at serving-bench scales this
-    is a few KB — a reservoir is not worth the accounting distortion.
+    per-flush residence times, from the start of the flush's host
+    compile to its retire; flushes OVERLAP, so the sum is not wall
+    clock — end-to-end wall clock is what the scheduler bench measures;
+    the pipelining gain shows up here as ``hidden_compile_s`` (host
+    compile time that ran while a previous flush executed on device)
+    over ``host_compile_s``.  Latency samples are kept raw (one float
+    per flush / per submit) so ``summary()`` can report percentiles; at
+    serving-bench scales this is a few KB — a reservoir is not worth the
+    accounting distortion.
+
+    The front-door and engine counters (``submit_s`` … ``routed``) are
+    ``perf_counter`` sums, each written by one thread or under the lock
+    already held where it is written.  Wall-clock time on either thread
+    includes the time it waited for the GIL.  ``stamps`` holds the
+    keyed submit and completion stamps of the async paths
+    (:class:`~repro.serve.stamps.CompletionStamps`).
     """
 
     num_shards: int
@@ -210,11 +222,20 @@ class ShardedServeStats:
     host_compile_s: float = 0.0            # Σ per-flush host compile time
     hidden_compile_s: float = 0.0          # … of which overlapped device exec
     in_flight_peak: int = 0                # deepest dispatch queue seen
+    # compile start → retire, one sample per flush
     flush_wall: List[float] = dataclasses.field(default_factory=list)
     submit_wall: List[float] = dataclasses.field(default_factory=list)
-    # submit-stamp → result-materialized, one sample per async query
-    # (quarantined queries never complete, so they never sample)
-    e2e_wall: List[float] = dataclasses.field(default_factory=list)
+    # ---- front door and engine thread (DESIGN.md §7.2) ----
+    submit_s: float = 0.0                  # Σ time inside accepted submit()s
+    submits: int = 0                       # accepted submit() calls
+    handoff_full_s: float = 0.0            # … of which blocked on a full hand-off
+    engine_wait_s: float = 0.0             # driver blocked on an empty hand-off
+    route_s: float = 0.0                   # ingest time outside flush work
+    routed: int = 0                        # bags ingested by the engine
+    # submit and completion stamps keyed by (producer, table, local seq);
+    # quarantined queries never complete, so they never get a record
+    stamps: CompletionStamps = dataclasses.field(
+        default_factory=CompletionStamps)
     # ---- online replanning (DESIGN.md §6) ----
     replans: int = 0                       # patches applied (moves > 0)
     rebases: int = 0                       # no-op patches (load reanchor only)
@@ -237,7 +258,9 @@ class ShardedServeStats:
 
     def record(self, sbq, dim: int, wall_s: float, queries: int) -> None:
         """Accounts one served batch: grid cells, widths, combine
-        traffic (scaled to the flush's participant set), wall time."""
+        traffic (scaled to the flush's participant set), and its
+        residence ``wall_s`` (compile start → retire on the async
+        paths, the whole ``serve()`` call on the sync one)."""
         cells = sbq.grid_cells_per_shard()
         self.batches += 1
         self.queries += queries
@@ -277,6 +300,16 @@ class ShardedServeStats:
         the thread driver — the never-blocks contract the percentiles
         in :meth:`summary` make auditable)."""
         self.submit_wall.append(seconds)
+
+    def record_accepted(self, t0: float, blocked: float = 0.0) -> None:
+        """Adds one accepted submit() that started at ``t0`` to the
+        front-door counters, ``blocked`` seconds of it on a full
+        hand-off queue.  The caller holds the lock that serializes its
+        path's submits (the stamp lock, or the engine lock under
+        ``"global"``)."""
+        self.submit_s += time.perf_counter() - t0
+        self.submits += 1
+        self.handoff_full_s += blocked
 
     def record_compile(self, seconds: float, *, hidden: bool) -> None:
         """Accounts one flush's host compile; ``hidden`` when at least
@@ -333,7 +366,7 @@ class ShardedServeStats:
             },
             "flush_latency_s": _latency_percentiles(self.flush_wall),
             "submit_latency_s": _latency_percentiles(self.submit_wall),
-            "e2e_latency_s": _latency_percentiles(self.e2e_wall),
+            "e2e_latency_s": _latency_percentiles(self.stamps.latencies()),
             "barrier_flushes": self.barrier_flushes,
             "deadline_flushes": self.deadline_flushes,
             "host_compile_s": self.host_compile_s,
@@ -488,15 +521,24 @@ class ShardedEmbeddingServer:
         self.interpret = interpret
 
         eq1_batch = batch_size_for_eq1 or batch_size
+        #: seconds of the plan build's stages, summed over tables:
+        #: ``cooccurrence``, ``grouping`` (grouping, Eq.-1 replication,
+        #: layout) and ``placement`` (shard plan, images, device put)
+        self.setup_timings = {"cooccurrence": 0.0, "grouping": 0.0,
+                              "placement": 0.0}
         self.layouts, plans, gfreqs = [], [], []
         dims = set()
         for name in self.names:
             table = np.asarray(tables[name])
             hist = histories[name]
+            t0 = time.perf_counter()
             graph = build_cooccurrence(hist, table.shape[0])
+            t1 = time.perf_counter()
             grouping = correlation_aware_grouping(graph, group_size)
             plan = plan_replication(grouping, graph.freq, eq1_batch)
             self.layouts.append(build_layout(grouping, plan, table.shape[1]))
+            self.setup_timings["cooccurrence"] += t1 - t0
+            self.setup_timings["grouping"] += time.perf_counter() - t1
             plans.append(plan)
             gfreqs.append(grouping.group_freq(graph.freq))
             dims.add(table.shape[1])
@@ -509,6 +551,7 @@ class ShardedEmbeddingServer:
             # paging rides the drift tracker: tiering without an explicit
             # replan config still needs one to ever page a group in
             replan = ReplanConfig()
+        t0 = time.perf_counter()
         self._capacity_tiles: Optional[int] = None
         if tiers is not None:
             # the budget is resolved against what an UNCAPPED plan of
@@ -558,6 +601,7 @@ class ShardedEmbeddingServer:
             )
             images = np.concatenate([images, pad], axis=1)
         self.shard_images = self._place_images(images)
+        self.setup_timings["placement"] = time.perf_counter() - t0
         #: host→device bytes of one fused tile — the paging_bytes unit
         self._tile_bytes = int(self._fused[0].nbytes) if len(self._fused) else 0
         self._tile_group = np.repeat(
@@ -699,9 +743,12 @@ class ShardedEmbeddingServer:
         # flight, or inline ingest running) — the seq-reset guard and
         # close()'s drain loop both key off this being zero
         self._pending_submits = 0
-        # submit-stamp timestamps, popped when the row materializes —
-        # the e2e_latency_s samples (async paths only)
-        self._e2e_t0: Dict[Tuple[str, int], float] = {}
+        # ---- engine accounting (DESIGN.md §7.2), written only by the
+        # engine (the driver thread, or under the engine lock inline):
+        # dispatch sequence number of the next flush (the spans' id),
+        # and the flush work that route_s leaves out ----
+        self._flush_seq = 0
+        self._flush_work_s = 0.0
 
     # ------------------------------------------------------------ serving --
 
@@ -1101,13 +1148,16 @@ class ShardedEmbeddingServer:
         """
         t0 = time.perf_counter()
         try:
-            return self._submit(table, query, producer)
+            return self._submit(table, query, producer, t0)
         finally:
             self.stats.record_submit(time.perf_counter() - t0)
 
     def _submit(
-        self, table: str, query: Sequence[int], producer=None
+        self, table: str, query: Sequence[int], producer, t0: float
     ) -> Dict[str, jax.Array]:
+        """The body of :meth:`submit`; ``t0`` is its entry time, the
+        bag's submit stamp.  Each accepted path adds its time to
+        ``stats.submit_s`` under the lock it already holds last."""
         if table not in self._buffer:  # unlocked: key set frozen at init
             raise KeyError(f"unknown table {table!r}")
         ids = np.asarray(list(query), dtype=np.int64)
@@ -1137,19 +1187,30 @@ class ShardedEmbeddingServer:
                     if self._driver is None:
                         self._start_driver()
                     handoff = self._handoff
-                    self._e2e_t0[(table, seq)] = time.perf_counter()
+                    self.stats.stamps.submitted(table, seq, t0)
                     self._pending_submits += 1
+                item = ("query", table, seq, list(query))
+                blocked = 0.0
                 try:
-                    handoff.put(("query", table, seq, list(query)))
+                    if handoff.full():
+                        # backpressure: only a put that waits is timed
+                        # (another producer may fill the queue between
+                        # the check and the put: then it goes untimed)
+                        tb = time.perf_counter()
+                        handoff.put(item)
+                        blocked = time.perf_counter() - tb
+                    else:
+                        handoff.put(item)
                 finally:
                     with self._stamp_lock:
                         self._pending_submits -= 1
+                        self.stats.record_accepted(t0, blocked)
                 return {}
             with self._stamp_lock:
                 if self._closed:
                     raise RuntimeError("submit() on a closed server")
                 seq = self._registry.stamp(producer, table)
-                self._e2e_t0[(table, seq)] = time.perf_counter()
+                self.stats.stamps.submitted(table, seq, t0)
                 self._pending_submits += 1
             try:
                 # the inline engine is not re-entrant: concurrent
@@ -1161,13 +1222,14 @@ class ShardedEmbeddingServer:
             finally:
                 with self._stamp_lock:
                     self._pending_submits -= 1
+                    self.stats.record_accepted(t0)
             return {}
         with self._engine_lock:
             self._buffer[table].append(list(query))
             self._buffered += 1
-            if self._buffered >= self.batch_size:
-                return self.flush()
-        return {}
+            out = self.flush() if self._buffered >= self.batch_size else {}
+            self.stats.record_accepted(t0)
+            return out
 
     def register_producer(self, producer=None) -> int:
         """Pre-registers a producer label, returning its pid.
@@ -1230,10 +1292,14 @@ class ShardedEmbeddingServer:
         where ``_completed`` is owned (the driver thread, when running),
         because a due host flush appends results directly.
         """
-        if self._route_host(table, seq, query):
-            return
-        self.scheduler.push(table, seq, query)
-        self._maybe_flush()
+        t0 = time.perf_counter()
+        work0 = self._flush_work_s
+        if not self._route_host(table, seq, query):
+            self.scheduler.push(table, seq, query)
+            self._maybe_flush()
+        self.stats.route_s += (time.perf_counter() - t0
+                               - (self._flush_work_s - work0))
+        self.stats.routed += 1
 
     def _route_host(self, table: str, seq: int, query) -> bool:
         """Detours a cold query into the host fetch queue.
@@ -1267,7 +1333,9 @@ class ShardedEmbeddingServer:
             return
         if reason == "deadline":
             self.stats.host_deadline_flushes += 1
+        t0 = time.perf_counter()
         self._flush_host_queue()
+        self._flush_work_s += time.perf_counter() - t0
 
     def _flush_host_queue(self, *, forced: bool = False) -> None:
         """Serves every queued cold query via the host gather+sum path.
@@ -1296,9 +1364,10 @@ class ShardedEmbeddingServer:
             seqs, rows = rows_of.setdefault(table, ([], []))
             seqs.append(seq)
             rows.append(self._cold_row(table, query))
+        now = time.perf_counter()
         for table, (seqs, rows) in rows_of.items():
             self._record_completed(
-                table, np.asarray(seqs, dtype=np.int64), np.stack(rows)
+                table, np.asarray(seqs, dtype=np.int64), np.stack(rows), now
             )
         if not forced and self._staged is not None:
             # cold-dominated traffic may never trip a device flush — the
@@ -1331,11 +1400,15 @@ class ShardedEmbeddingServer:
         due = self.scheduler.due_homes()
         if not due:
             return
-        if self._staged is not None:
-            self._barrier()
-            return
-        for home in due:
-            self._flush_home(home)
+        t0 = time.perf_counter()
+        try:
+            if self._staged is not None:
+                self._barrier()
+                return
+            for home in due:
+                self._flush_home(home)
+        finally:
+            self._flush_work_s += time.perf_counter() - t0
 
     def _flush_home(self, home: int, *, forced: bool = False) -> None:
         """Compiles and dispatches one home's pending batch (no block).
@@ -1421,7 +1494,7 @@ class ShardedEmbeddingServer:
             for table, seq, _query in entries:
                 prod, local = self._registry.decode(seq)
                 ledger.quarantine(table, local, last, producer=prod)
-                self._e2e_t0.pop((table, seq), None)
+                self.stats.stamps.dropped(table, seq)
             self.scheduler.record_quarantine(len(entries))
             return []
         raise last
@@ -1476,6 +1549,8 @@ class ShardedEmbeddingServer:
         polling simulates the hung device.
         """
         t0 = time.perf_counter()
+        flush = self._flush_seq
+        self._flush_seq += 1
         if self._injector is not None:
             self._injector.on_compile(entries)
         by_table: Dict[str, Tuple[List[int], List[list]]] = {}
@@ -1484,10 +1559,11 @@ class ShardedEmbeddingServer:
             seqs.append(seq)
             qs.append(query)
         served = [n for n in self.names if n in by_table]
-        host_cq, sbq, spans = self._compile_batch(
-            served, {n: by_table[n][1] for n in served},
-            participants=participants,
-        )
+        with TraceAnnotation("recross.compile", flush=flush):
+            host_cq, sbq, spans = self._compile_batch(
+                served, {n: by_table[n][1] for n in served},
+                participants=participants,
+            )
         self.stats.record_compile(
             time.perf_counter() - t0, hidden=self._device_busy()
         )
@@ -1495,19 +1571,21 @@ class ShardedEmbeddingServer:
             self._injector.on_dispatch() if self._injector is not None
             else None
         )
-        outs = crossbar_reduce_tables(
-            self.shard_images, sbq, spans,
-            mesh=self.mesh, axis_name=self.axis_name,
-            combine=self.combine, combine_chunks=self.combine_chunks,
-            dynamic_switch=self.dynamic_switch, interpret=self.interpret,
-        )
+        with TraceAnnotation("recross.dispatch", flush=flush):
+            outs = crossbar_reduce_tables(
+                self.shard_images, sbq, spans,
+                mesh=self.mesh, axis_name=self.axis_name,
+                combine=self.combine, combine_chunks=self.combine_chunks,
+                dynamic_switch=self.dynamic_switch,
+                interpret=self.interpret,
+            )
         return _InFlight(
             outs=outs, sbq=sbq, served=served,
             seqs={n: np.asarray(by_table[n][0], dtype=np.int64)
                   for n in served},
             t0=t0, n_queries=sum(len(by_table[n][1]) for n in served),
             host_cq=host_cq,
-            t_dispatch=time.perf_counter(), hang_s=hang_s,
+            t_dispatch=time.perf_counter(), hang_s=hang_s, flush=flush,
         )
 
     def _retire_oldest(self) -> None:
@@ -1520,6 +1598,11 @@ class ShardedEmbeddingServer:
         policy, or requeues + re-raises under the legacy one.
         """
         e = self._in_flight.popleft()
+        with TraceAnnotation("recross.retire", flush=e.flush):
+            self._retire(e)
+
+    def _retire(self, e: _InFlight) -> None:
+        """The body of :meth:`_retire_oldest`, inside its span."""
         try:
             if self._injector is not None:
                 self._injector.on_retire()
@@ -1542,23 +1625,19 @@ class ShardedEmbeddingServer:
                 # the next barrier retries it, then the error surfaces
                 self.scheduler.requeue(e.home, e.entries)
             raise
-        self.stats.record(
-            e.sbq, self.dim, time.perf_counter() - e.t0, e.n_queries
-        )
-        for name, out in zip(e.served, outs):
-            self._record_completed(name, e.seqs[name], np.asarray(out))
+        rows = [np.asarray(out) for out in outs]
+        now = time.perf_counter()
+        self.stats.record(e.sbq, self.dim, now - e.t0, e.n_queries)
+        for name, r in zip(e.served, rows):
+            self._record_completed(name, e.seqs[name], r, now)
 
     def _record_completed(
-        self, table: str, seqs: np.ndarray, rows: np.ndarray
+        self, table: str, seqs: np.ndarray, rows: np.ndarray, now: float
     ) -> None:
-        """Stashes one flush's rows for :meth:`drain`, samples e2e
-        latency, under the results lock (a drain on another thread may
-        be extracting concurrently)."""
-        now = time.perf_counter()
-        for s in seqs:
-            t0 = self._e2e_t0.pop((table, int(s)), None)
-            if t0 is not None:
-                self.stats.e2e_wall.append(now - t0)
+        """Stashes one flush's rows of ``table`` for :meth:`drain`,
+        under the results lock (a drain on another thread may be
+        extracting concurrently), and stamps them complete at ``now``."""
+        self.stats.stamps.completed(table, seqs, now)
         with self._results_lock:
             self._completed[table].append((seqs, rows))
 
@@ -1616,13 +1695,12 @@ class ShardedEmbeddingServer:
             seqs, rows = rows_of.setdefault(table, ([], []))
             seqs.append(seq)
             rows.append(row.astype(tab.dtype, copy=False))
+        now = time.perf_counter()
         for table, (seqs, rows) in rows_of.items():
             self._record_completed(
-                table, np.asarray(seqs, dtype=np.int64), np.stack(rows)
+                table, np.asarray(seqs, dtype=np.int64), np.stack(rows), now
             )
-        self.stats.record(
-            e.sbq, self.dim, time.perf_counter() - e.t0, e.n_queries
-        )
+        self.stats.record(e.sbq, self.dim, now - e.t0, e.n_queries)
 
     def _barrier(self) -> None:
         """Flush-everything + drain + apply any staged patch atomically.
@@ -1652,15 +1730,17 @@ class ShardedEmbeddingServer:
                         break
                 self._raise_driver_error()
                 return
-        for home in self.scheduler.homes_with_pending():
-            self._flush_home(home, forced=True)
-        while self._in_flight:
-            self._retire_oldest()
-        # queued cold work drains with the pipeline (host rows read the
-        # master image, so ordering vs the patch below is immaterial —
-        # but a drain must hand back every submitted query's row)
-        self._flush_host_queue(forced=True)
-        self._apply_staged_patch()
+        with TraceAnnotation("recross.barrier", flush=self._flush_seq):
+            for home in self.scheduler.homes_with_pending():
+                self._flush_home(home, forced=True)
+            while self._in_flight:
+                self._retire_oldest()
+            # queued cold work drains with the pipeline (host rows read
+            # the master image, so ordering vs the patch below is
+            # immaterial — but a drain must hand back every submitted
+            # query's row)
+            self._flush_host_queue(forced=True)
+            self._apply_staged_patch()
         self.stats.barrier_flushes += 1
 
     # ------------------------------------------------------ thread driver --
@@ -1688,8 +1768,10 @@ class ShardedEmbeddingServer:
         """
         while not self._driver_stop.is_set():
             try:
-                item = self._handoff.get(timeout=0.005)
+                item = self._handoff.get_nowait()
             except queue.Empty:
+                item = self._wait_for_item()
+            if item is None:
                 try:
                     self._retire_ready()
                     # a wall deadline (policy.deadline_s) must fire even
@@ -1725,6 +1807,17 @@ class ShardedEmbeddingServer:
                 # empty() and the scheduler — unfinished_tasks is the
                 # counter that still sees it (seq-reset guard)
                 self._handoff.task_done()
+
+    def _wait_for_item(self):
+        """Blocks up to 5 ms on the empty hand-off queue for the next
+        item (``None`` if none came); the wait is ``engine_wait_s``."""
+        t0 = time.perf_counter()
+        try:
+            return self._handoff.get(timeout=0.005)
+        except queue.Empty:
+            return None
+        finally:
+            self.stats.engine_wait_s += time.perf_counter() - t0
 
     def _retire_ready(self) -> None:
         """Retires in-flight flushes whose outputs are already
@@ -1987,6 +2080,24 @@ class ShardedEmbeddingServer:
 
                             validate_server_state(self, quiesced=True)
                         self._registry.reset_seqs()
+                        # records of the old sequence spaces close
+                        # with them: local seqs restart at 0
+                        self.stats.stamps.seal()
+        return out
+
+    def take_completion_stamps(self) -> List[StampRecords]:
+        """Takes the keyed stamps of every bag completed since the last
+        take (async policies): one :class:`~repro.serve.stamps.
+        StampRecords` per ``(epoch, producer, table)``, with the local
+        seq, the submit stamp and the completion stamp of each bag
+        (``perf_counter`` seconds), ``producer`` the label the bags were
+        submitted under.  An epoch ends at each quiesced :meth:`drain`,
+        which restarts local seqs at 0.  Taking clears the records;
+        quarantined bags never complete and have none."""
+        labels = self._registry.producers()
+        out = self.stats.stamps.take()
+        for r in out:
+            r.producer = labels[r.producer]
         return out
 
     # ------------------------------------------------------------- report --

@@ -158,7 +158,8 @@ def test_retry_policy_backoff_and_legacy():
 def test_legacy_requeue_and_reraise_branches(num_shards, threaded, kind):
     """The pre-§8 contract, provoked by the injector instead of
     monkeypatching: a dispatch-time fault requeues the batch, the error
-    surfaces (inline: at submit; threaded: at the next drain), and a
+    surfaces (inline: at submit; threaded: at the next submit or
+    drain), and a
     later drain retries the requeued work — every row served, in
     order, bit-identical to the oracle."""
     plan = FaultPlan([], seed=1).add(kind, tick=0, times=1)
@@ -173,9 +174,14 @@ def test_legacy_requeue_and_reraise_branches(num_shards, threaded, kind):
             srv.submit(name, q)
         except InjectedFault as e:
             raised = e
-    if threaded:
+            if threaded:
+                # a failure stashed by the driver surfaces at the top
+                # of the next submit(), which then accepts nothing
+                srv.submit(name, q)
+    if threaded and raised is None:
         # the failure happened on the driver thread; it surfaces at the
-        # next submit()/drain() instead of the submit that tripped it
+        # next submit()/drain() instead of the submit that tripped it —
+        # here the replay's submits all ran before it was stashed
         with pytest.raises(InjectedFault):
             deadline = time.monotonic() + 10.0
             while time.monotonic() < deadline:

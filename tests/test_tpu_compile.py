@@ -126,3 +126,16 @@ def test_mesh_subset_dispatch_compiles(mesh):
     args = _stacked_args(mesh.shape["model"], NamedSharding(mesh, P("model")))
     text = _assert_kernel_compiled(fn.lower(*args))
     assert "all-reduce" in text
+
+
+def test_flush_program_names_its_kernel(one_chip):
+    """The flush program is ``jit_recross_flush`` and each of its Pallas
+    calls is an HLO instruction named ``recross_crossbar_reduce``, so a
+    device trace can find the kernel by name."""
+    fn = _emulated_fn((0,), 2, True, False)
+    text = _assert_kernel_compiled(fn.lower(*_stacked_args(1, one_chip)))
+    assert text.startswith("HloModule jit_recross_flush")
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2
+    assert all(line.lstrip().startswith("%recross_crossbar_reduce")
+               for line in calls)
