@@ -412,8 +412,9 @@ class BlockUnionTracker:
       * :meth:`grid_cells` — ``nb × padded max width``, the same
         sublane-padded accounting as :func:`shard_block_queries`.
 
-    ``add`` takes the query's distinct activated *group* ids (host-side
-    routing already computes them); O(groups-per-query) per call.
+    :meth:`extend` takes a run of queries' activated *group* ids
+    (host-side routing already computes them) and folds the run's blocks
+    in one vectorized pass.
     """
 
     def __init__(self, q_block: int):
@@ -428,14 +429,58 @@ class BlockUnionTracker:
         self._max_width = 0
         self._block: set = set()  # current partial block's union
 
-    def add(self, groups) -> None:
-        """Appends one query (its distinct activated group ids)."""
+    def _reference_add(self, groups) -> None:
+        """Per-query set-union oracle of :meth:`extend`."""
         if self._n and self._n % self.q_block == 0:
             self._filled += len(self._block)
             self._max_width = max(self._max_width, len(self._block))
             self._block = set()
         self._block.update(int(g) for g in groups)
         self._n += 1
+
+    def extend(self, groups, sizes) -> None:
+        """Appends a run of queries in one pass.
+
+        Args:
+          groups: the run's group ids, query after query (``sizes[i]``
+            ids for query ``i``; duplicates are harmless).
+          sizes: ids per query, one entry per appended query (0 for a
+            query that touches nothing).
+        """
+        sizes = np.asarray(sizes, dtype=np.int64)
+        m = int(sizes.size)
+        if m == 0:
+            return
+        q, n0 = self.q_block, self._n
+        if n0 and n0 % q == 0:
+            # the current block is complete: close it before the run
+            self._filled += len(self._block)
+            self._max_width = max(self._max_width, len(self._block))
+            self._block = set()
+        groups = np.asarray(groups, dtype=np.int64).ravel()
+        b0 = n0 % q                     # the run's place in its first block
+        last = (b0 + m - 1) // q        # the run's last block, relative
+        if last:
+            # blocks 0..last-1 complete inside the run: one sort of
+            # packed (block, group) keys gives each one's union width
+            k = last * q - b0           # first query of the last block
+            cut = int(sizes[:k].sum())
+            head = groups[:cut]
+            blk = np.repeat((b0 + np.arange(k, dtype=np.int64)) // q, sizes[:k])
+            if self._block:             # the open block is block 0
+                held = np.fromiter(self._block, np.int64, len(self._block))
+                head = np.concatenate([held, head])
+                blk = np.concatenate([np.zeros(held.size, np.int64), blk])
+            span = int(head.max()) + 1 if head.size else 1
+            _check_block_key_capacity(last, span, "BlockUnionTracker.extend")
+            key = np.unique(blk * span + head)
+            widths = np.bincount(key // span, minlength=last)
+            self._filled += int(widths.sum())
+            self._max_width = max(self._max_width, int(widths.max()))
+            self._block = set()
+            groups = groups[cut:]
+        self._block.update(groups.tolist())
+        self._n += m
 
     @property
     def pending(self) -> int:

@@ -48,12 +48,14 @@ DESIGN.md §7.3.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.reduction import BlockUnionTracker
+from repro.core.reduction import BlockUnionTracker, _check_block_key_capacity
+from repro.dist.shard_plan import COLD
 from repro.serve.producers import DEFAULT_PRODUCER
 
 #: pseudo-home for pooled multi-owner queries, flushed over their owner
@@ -62,6 +64,13 @@ from repro.serve.producers import DEFAULT_PRODUCER
 POOL = -1
 
 _KINDS = ("global", "per-shard", "deadline", "owner-set")
+
+
+def _cold_message(table: str) -> str:
+    # no shard holds a cold group's tile, so no flush home can serve the
+    # query: the server must have detoured it to its host fetch queue
+    return (f"query on table {table!r} touches a cold (host-tier) group; "
+            "cold queries take the host path, not a flush home")
 
 
 @dataclasses.dataclass
@@ -176,14 +185,38 @@ class FlushPolicy:
 Home = object
 
 
+def _distinct(keys: np.ndarray) -> np.ndarray:
+    """Sorted distinct values of a 1-D int64 array (sorts it in place)."""
+    keys.sort()
+    keep = np.empty(keys.size, bool)
+    keep[:1] = True
+    np.not_equal(keys[1:], keys[:-1], out=keep[1:])
+    return keys[keep]
+
+
+@dataclasses.dataclass(slots=True)
+class _Routed:
+    """One routing pass over a run of entries (:meth:`FlushScheduler.
+    _route_many`); bag ``i`` is entry ``base + i`` of the run."""
+
+    version: int            # plan version routed against
+    base: int
+    tix: List[int]          # table index per bag
+    gstart: List[int]       # (n + 1) offsets of each bag's groups in gval
+    gval: np.ndarray        # distinct fused groups, bag by bag, ascending
+    owners: List[Tuple[int, ...]]  # each bag's sorted distinct owners
+    first_cold: int         # first bag touching a cold group (n if none)
+
+
 class FlushScheduler:
     """Routes queries to flush homes and tracks per-home fill state.
 
     One *home* per shard (single-owner and replicated-only queries) plus
     either the :data:`POOL` home (pooled kinds) or one lazily-created
     home per distinct frozen owner set (``owner-set`` kind) for
-    multi-owner queries.  All state is host NumPy/sets; ``route``/
-    ``push`` are O(rows in the query).
+    multi-owner queries.  All state is host NumPy/sets; ``push_many``
+    routes a run of queries in one vectorized pass, O(rows in the run)
+    NumPy work plus a sort.
 
     Args:
       plan: the live :class:`~repro.dist.shard_plan.ShardPlan` (only
@@ -213,6 +246,7 @@ class FlushScheduler:
         #: the routed stream; pending_by_producer in :meth:`state` is
         #: the instantaneous complement)
         self.pushed_by_producer: Dict[str, int] = {}
+        self._version = 0
         self._group_of = {
             name: np.asarray(layout.group_of, dtype=np.int64)
             for name, layout in zip(self.names, layouts)
@@ -237,6 +271,10 @@ class FlushScheduler:
         self._tick = 0
         self._rr = 0
         self._pool_owners: set = set()
+        # bags push_many enqueued since the last union fold, by home, as
+        # indices into the routing pass that placed them (_settle)
+        self._untracked: Dict[Home, List[int]] = {}
+        self._routed: Optional[_Routed] = None
         #: failure-path accounting (DESIGN.md §8): batches put back by a
         #: failed dispatch, and queries permanently dropped after
         #: offender bisection isolated them
@@ -256,12 +294,25 @@ class FlushScheduler:
         """
         self.num_shards = int(plan.num_shards)
         shard_of_group = np.asarray(plan.shard_of_group, dtype=np.int64)
+        self._shard_of_group = shard_of_group
+        self._has_cold = bool((shard_of_group == COLD).any())
         self._owner_of_row = {}
         self._fused_group_of_row = {}
         for seg in plan.tables:
             gof = self._group_of[seg.name] + seg.group_offset
             self._fused_group_of_row[seg.name] = gof
             self._owner_of_row[seg.name] = shard_of_group[gof]
+        # the tables' row→fused-group arrays end to end, so a run that
+        # mixes tables routes with one gather (_route_many)
+        self._table_names = [seg.name for seg in plan.tables]
+        self._table_ix = {n: i for i, n in enumerate(self._table_names)}
+        gofs = [self._fused_group_of_row[n] for n in self._table_names]
+        self._table_rows = np.asarray([g.size for g in gofs], np.int64)
+        self._row_base = np.cumsum(self._table_rows) - self._table_rows
+        self._group_cat = (np.concatenate(gofs) if gofs
+                           else np.empty(0, np.int64))
+        # routes made against an older plan are stale (push_many)
+        self._version += 1
 
     def route(self, table: str, query: Sequence[int]) -> Tuple[Home, np.ndarray]:
         """Home of one query + its distinct fused group ids (a PEEK —
@@ -274,47 +325,202 @@ class FlushScheduler:
         one → that shard; several → the sorted owner-set tuple under
         ``owner-set`` routing, else the cross-shard :data:`POOL`.
         """
-        home, groups, _ = self._route(table, query, advance=False)
-        return home, groups
+        r = self._route_many([(table, None, query)])
+        if r.first_cold == 0:
+            raise ValueError(_cold_message(table))
+        return self._home(r.owners[0], advance=False), r.gval
 
-    def _route(
-        self, table: str, query, *, advance: bool
-    ) -> Tuple[Home, np.ndarray, np.ndarray]:
+    def _home(self, owners: Tuple[int, ...], *, advance: bool) -> Home:
+        """The home of a query with these sorted distinct owners
+        (:meth:`route`'s rule); ``advance`` consumes the round-robin
+        slot an owner-less query takes."""
+        if not owners:
+            home = self._rr
+            if advance:
+                self._rr = (self._rr + 1) % self.num_shards
+            return home
+        if len(owners) == 1:
+            return owners[0]
+        cap = self.policy.owner_set_max
+        if self.policy.owner_set_routing and (cap is None or len(owners) <= cap):
+            # sorted distinct owners: the canonical frozen owner set, one
+            # home per distinct set.  Sets wider than owner_set_max fall
+            # through to the pool — the subset win shrinks as a set
+            # approaches the mesh while home fragmentation grows
+            return owners
+        return POOL
+
+    def push(self, table: str, seq: int, query: Sequence[int]) -> Home:
+        """Routes and enqueues one query; returns its home (owner-set
+        homes are created lazily on first sight).  The one-entry case of
+        :meth:`push_many`."""
+        return self.push_many([(table, seq, list(query))])[0]
+
+    def push_many(
+        self,
+        entries: Sequence[Tuple[str, int, list]],
+        flush: Optional[Callable[[], None]] = None,
+    ) -> List[Home]:
+        """Routes and enqueues a run of ``(table, seq, query)`` entries in
+        FIFO order; returns each entry's home.
+
+        One routing pass covers the run (:meth:`_route_many`).  The bags
+        are then enqueued one by one, each followed by the policy's own
+        due check (:meth:`due_homes`); the run stops at the first due
+        point, ``flush`` (the server's maybe-flush) runs, and the rest
+        follows.  So every flush sees exactly the pending entries, in the
+        order, that one push and one due check per entry would have
+        given it.  The union trackers take the bags pushed since the last
+        due point in one fold (:meth:`_settle`), which :meth:`fill` runs
+        first, so a ``union_budget`` check reads the fill up to the bag
+        just pushed.  A flush that patched the plan (a barrier) re-routes
+        the rest of the run against the new plan.  The entries are
+        stored as given: the caller hands over their query lists.
+
+        Raises:
+          ValueError: an entry touches a cold (host-tier) group; the
+            entries before it are pushed, it and the rest are not.
+        """
+        homes: List[Home] = []
+        pending, untracked = self._pending, self._untracked
+        first_tick, first_wall = self._first_tick, self._first_wall
+        pushed, decode = self.pushed_by_producer, self._seq_decode
+        i, n, r = 0, len(entries), None
+        while i < n:
+            if r is None or r.version != self._version:
+                r = self._route_many(entries[i:], base=i)
+            lo = i - r.base
+            if lo == r.first_cold:
+                raise ValueError(_cold_message(entries[i][0]))
+            self._routed = r
+            end = r.first_cold
+            for b in range(lo, end):
+                home = self._home(r.owners[b], advance=True)
+                entry = entries[i]
+                pending.setdefault(home, []).append(entry)
+                untracked.setdefault(home, []).append(b)
+                if home not in first_tick:
+                    first_tick[home] = self._tick
+                if home not in first_wall:
+                    first_wall[home] = time.monotonic()
+                if home == POOL:
+                    self._pool_owners.update(r.owners[b])
+                label = str(decode(entry[1])[0])
+                pushed[label] = pushed.get(label, 0) + 1
+                self._tick += 1
+                homes.append(home)
+                i += 1
+                # after the run's last bag the flush below checks anyway
+                if b + 1 < end and self.due_homes():
+                    break
+            for home in list(untracked):
+                self._settle(home)
+            if flush is not None:
+                flush()
+        return homes
+
+    def _route_many(self, entries, base: int = 0) -> "_Routed":
+        """Each entry's distinct fused groups and sorted distinct owner
+        shards, for a run of entries in one NumPy pass (state untouched).
+
+        Concatenates the run's ids, gathers each id's fused group from
+        the tables' concatenated row→group array (one gather, whatever
+        the mix of tables), and sorts packed ``(bag, group)`` keys once
+        for each bag's distinct groups, and their owners' packed ``(bag,
+        owner)`` keys for each bag's owner set.  A run of one takes the
+        same steps without the bag keys.
+        """
+        n, S = len(entries), self.num_shards
+        G = int(self._shard_of_group.size)
+        if n == 1:
+            table, _, query = entries[0]
+            t = self._table_ix[table]
+            ids = np.asarray(query, np.int64)
+            # one unsigned compare catches negative ids too
+            if (ids.view(np.uint64) >= self._table_rows[t]).any():
+                raise IndexError("query row ids out of range for their table")
+            gval = _distinct(self._fused_group_of_row[table][ids])
+            gbag = None
+            tix, gstart = [t], [0, int(gval.size)]
+        else:
+            lens = np.fromiter((len(e[2]) for e in entries), np.int64, n)
+            ids = np.fromiter(
+                itertools.chain.from_iterable(e[2] for e in entries),
+                np.int64, int(lens.sum()))
+            tix_a = np.fromiter((self._table_ix[e[0]] for e in entries),
+                                np.int64, n)
+            t_of = np.repeat(tix_a, lens)
+            if (ids.view(np.uint64) >= self._table_rows[t_of]).any():
+                raise IndexError("query row ids out of range for their table")
+            _check_block_key_capacity(n, max(G, S),
+                                      "FlushScheduler._route_many")
+            key = _distinct(np.repeat(np.arange(n, dtype=np.int64) * G, lens)
+                            + self._group_cat[ids + self._row_base[t_of]])
+            gbag = key // G
+            gval = key - gbag * G
+            tix = tix_a.tolist()
+            gstart = np.searchsorted(gbag, np.arange(n + 1)).tolist()
+        own = self._shard_of_group[gval]
+        first_cold = n
+        if self._has_cold:
+            cold = np.flatnonzero(own == COLD)
+            if cold.size:
+                first_cold = 0 if gbag is None else int(gbag[cold[0]])
+        owned = own >= 0
+        if gbag is None:
+            owners = [tuple(_distinct(own[owned]).tolist())]
+        else:
+            okey = _distinct(gbag[owned] * S + own[owned])
+            obag = okey // S
+            oval = (okey - obag * S).tolist()
+            of = np.searchsorted(obag, np.arange(n + 1)).tolist()
+            owners = [tuple(oval[of[b]:of[b + 1]]) for b in range(n)]
+        return _Routed(
+            version=self._version, base=base, tix=tix, gstart=gstart,
+            gval=gval, owners=owners, first_cold=first_cold,
+        )
+
+    def _settle(self, home: Home) -> None:
+        """Folds the home's bags pushed since its last fold into its
+        union trackers."""
+        bags = self._untracked.pop(home, None)
+        if bags:
+            self._track(home, self._routed, bags)
+
+    def _track(self, home: Home, r: "_Routed", bags: Sequence[int]) -> None:
+        """Folds routed bags (in order) into the home's per-table union
+        trackers, one :meth:`BlockUnionTracker.extend` per table."""
+        trackers = self._trackers.setdefault(home, {})
+        gs = r.gstart
+        by_table: Dict[int, List[int]] = {}
+        for b in bags:
+            by_table.setdefault(r.tix[b], []).append(b)
+        for t, mine in by_table.items():
+            trackers.setdefault(
+                self._table_names[t], BlockUnionTracker(self.q_block)
+            ).extend(np.concatenate([r.gval[gs[b]:gs[b + 1]] for b in mine]),
+                     [gs[b + 1] - gs[b] for b in mine])
+
+    def _reference_push(self, table: str, seq: int, query: Sequence[int]) -> Home:
+        """Per-query oracle of :meth:`push_many` (three ``np.unique`` and
+        a set update per query), kept to pin the vectorized path."""
         rows = np.unique(np.asarray(query, dtype=np.int64))
         groups = np.unique(self._fused_group_of_row[table][rows])
         owners = np.unique(self._owner_of_row[table][rows])
-        if owners.size and owners[0] == -2:
-            # COLD sentinel (repro.dist.shard_plan): no shard holds the
-            # tile, so no flush home can serve it — the server must have
-            # detoured this query to its host fetch queue before routing
-            raise ValueError(
-                f"query on table {table!r} touches a cold (host-tier) "
-                "group; cold queries take the host path, not a flush home"
-            )
+        if owners.size and owners[0] == COLD:
+            raise ValueError(_cold_message(table))
         owners = owners[owners >= 0]
         if owners.size == 0:
             home: Home = self._rr
-            if advance:
-                self._rr = (self._rr + 1) % self.num_shards
+            self._rr = (self._rr + 1) % self.num_shards
         elif owners.size == 1:
             home = int(owners[0])
         elif (self.policy.owner_set_routing
               and (self.policy.owner_set_max is None
                    or owners.size <= self.policy.owner_set_max)):
-            # np.unique already sorted the owners: the tuple is the
-            # canonical frozen owner set, one home per distinct set.
-            # Sets wider than owner_set_max fall through to the pool —
-            # the subset win shrinks as a set approaches the mesh while
-            # home fragmentation grows, so the tail is not worth keying.
             home = tuple(int(o) for o in owners)
         else:
             home = POOL
-        return home, groups, owners
-
-    def push(self, table: str, seq: int, query: Sequence[int]) -> Home:
-        """Routes and enqueues one query; returns its home (owner-set
-        homes are created lazily on first sight)."""
-        home, groups, owners = self._route(table, query, advance=True)
         if home == POOL:
             self._pool_owners.update(int(o) for o in owners)
         label = str(self._seq_decode(seq)[0])
@@ -324,7 +530,7 @@ class FlushScheduler:
         self._pending.setdefault(home, []).append((table, seq, list(query)))
         self._trackers.setdefault(home, {}).setdefault(
             table, BlockUnionTracker(self.q_block)
-        ).add(groups)
+        )._reference_add(groups)
         self._first_tick.setdefault(home, self._tick)
         self._first_wall.setdefault(home, time.monotonic())
         self._tick += 1
@@ -364,16 +570,11 @@ class FlushScheduler:
         self.requeues += 1
         self._pending[home] = list(entries) + self._pending.get(home, [])
         self._trackers[home] = {}
-        for table, _seq, query in self._pending[home]:
-            rows = np.unique(np.asarray(query, dtype=np.int64))
-            self._trackers[home].setdefault(
-                table, BlockUnionTracker(self.q_block)
-            ).add(np.unique(self._fused_group_of_row[table][rows]))
-            if home == POOL:
-                owners = np.unique(self._owner_of_row[table][rows])
-                self._pool_owners.update(
-                    int(o) for o in owners if o >= 0
-                )
+        r = self._route_many(self._pending[home])
+        self._track(home, r, range(len(r.owners)))
+        if home == POOL:
+            for owners in r.owners:
+                self._pool_owners.update(owners)
         if first_tick is not None:
             self._first_tick[home] = min(
                 first_tick, self._first_tick.get(home, first_tick)
@@ -432,6 +633,7 @@ class FlushScheduler:
     def fill(self, home: Home) -> int:
         """Σ block-union widths over the home's pending per-table
         streams — the tile-DMA count a flush-now would run."""
+        self._settle(home)
         return sum(tr.fill for tr in self._trackers[home].values())
 
     def homes_with_pending(self) -> List[Home]:
@@ -478,7 +680,8 @@ class FlushScheduler:
         (GIL-atomic) ``list()`` copies before iteration, so a
         concurrently-created owner-set home can never raise
         ``dictionary changed size during iteration`` — the snapshot is
-        merely allowed to be one push stale.
+        merely allowed to be one push stale (its union fill, up to one
+        run of :meth:`push_many`, whose trackers fold at due points).
         """
         pending_items = list(self._pending.items())
         union_fill = {}

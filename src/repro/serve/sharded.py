@@ -232,6 +232,7 @@ class ShardedServeStats:
     engine_wait_s: float = 0.0             # driver blocked on an empty hand-off
     route_s: float = 0.0                   # ingest time outside flush work
     routed: int = 0                        # bags ingested by the engine
+    route_chunks: int = 0                  # routing passes over those bags
     # submit and completion stamps keyed by (producer, table, local seq);
     # quarantined queries never complete, so they never get a record
     stamps: CompletionStamps = dataclasses.field(
@@ -373,6 +374,8 @@ class ShardedServeStats:
             "hidden_compile_s": self.hidden_compile_s,
             "overlap_fraction": self.overlap_fraction,
             "in_flight_peak": self.in_flight_peak,
+            "routed": self.routed,
+            "route_chunks": self.route_chunks,
             "replans": self.replans,
             "rebases": self.rebases,
             "patched_tiles": self.patched_tiles,
@@ -1218,7 +1221,7 @@ class ShardedEmbeddingServer:
                 # flush — the never-blocks contract is the thread
                 # driver's, not the inline engine's)
                 with self._engine_lock:
-                    self._ingest(table, seq, query)
+                    self._ingest_many([(table, seq, list(query))])
             finally:
                 with self._stamp_lock:
                     self._pending_submits -= 1
@@ -1284,22 +1287,51 @@ class ShardedEmbeddingServer:
 
     # ------------------------------------------- tiered host path (§9) ----
 
-    def _ingest(self, table: str, seq: int, query) -> None:
-        """Routes one stamped query by residency, then into the engine.
+    def _ingest_many(self, items: List[tuple], on_error=None) -> None:
+        """Routes a run of stamped ``(table, seq, query)`` items into the
+        engine, flushing whatever falls due on the way.
 
         The single entry point shared by the inline async submit path
-        and the thread driver's loop — residency routing must happen
-        where ``_completed`` is owned (the driver thread, when running),
-        because a due host flush appends results directly.
+        (a run of one) and the thread driver's loop (a run of what the
+        hand-off queue held) — routing must happen where ``_completed``
+        is owned (the driver thread, when running), because a due host
+        flush appends results directly.  One
+        :meth:`FlushScheduler.push_many` routes the run; it stops at
+        every due point for :meth:`_maybe_flush`, so each flush holds
+        what one-by-one routing would have given it.  A tiered server
+        routes item by item: a host flush between two items can hit a
+        patch barrier that changes residency.
+
+        ``on_error`` (the driver's stash) takes a flush failure, and the
+        run goes on routing, as one-by-one ingest would have; without
+        it the failure propagates.
         """
         t0 = time.perf_counter()
         work0 = self._flush_work_s
-        if not self._route_host(table, seq, query):
-            self.scheduler.push(table, seq, query)
-            self._maybe_flush()
-        self.stats.route_s += (time.perf_counter() - t0
-                               - (self._flush_work_s - work0))
-        self.stats.routed += 1
+        flush = self._maybe_flush
+        if on_error is not None:
+            def flush():
+                try:
+                    self._maybe_flush()
+                except Exception as e:
+                    on_error(e)
+        # a tiered server's runs are one item long (see above)
+        runs = [items] if self._residency is None else [[i] for i in items]
+        try:
+            for run in runs:
+                self.stats.route_chunks += 1
+                try:
+                    if (self._residency is None
+                            or not self._route_host(*run[0])):
+                        self.scheduler.push_many(run, flush)
+                except Exception as e:
+                    if on_error is None:
+                        raise
+                    on_error(e)
+        finally:
+            self.stats.route_s += (time.perf_counter() - t0
+                                   - (self._flush_work_s - work0))
+            self.stats.routed += len(items)
 
     def _route_host(self, table: str, seq: int, query) -> bool:
         """Detours a cold query into the host fetch queue.
@@ -1756,9 +1788,12 @@ class ShardedEmbeddingServer:
     def _driver_loop(self) -> None:
         """Dispatch/retire loop of the thread driver (DESIGN.md §7.2).
 
-        Pops hand-off items FIFO: a query item routes + maybe-flushes
-        (exactly the inline engine's submit path), a barrier token runs
-        :meth:`_barrier` inline and wakes its waiter.  While the queue
+        Pops hand-off items FIFO: after a query item it keeps popping
+        without waiting, up to ``policy.batch_size`` query items or the
+        next barrier token, and routes the run in one
+        :meth:`_ingest_many` (the inline engine's submit path, run by
+        run); a barrier token runs :meth:`_barrier` inline, after the
+        run before it, and wakes its waiter.  While the queue
         is idle, in-flight flushes whose outputs are already
         materialized retire opportunistically, so result hand-off
         latency does not wait for the next submission.  A flush failure
@@ -1782,8 +1817,30 @@ class ShardedEmbeddingServer:
                 except Exception as e:  # device fault surfacing at retire
                     self._stash_driver_error(e)
                 continue
-            if item[0] == "barrier":
-                done = item[1]
+            token = item if item[0] == "barrier" else None
+            if token is None:
+                run = [item[1:]]
+                while len(run) < self.policy.batch_size:
+                    try:
+                        item = self._handoff.get_nowait()
+                    except queue.Empty:
+                        break
+                    if item[0] == "barrier":
+                        token = item
+                        break
+                    run.append(item[1:])
+                try:
+                    # a failed flush leaves its batch requeued; the
+                    # failure surfaces at the caller's next submit() or
+                    # drain() (retry contract)
+                    self._ingest_many(run, on_error=self._stash_driver_error)
+                finally:
+                    # a popped-but-unprocessed item is invisible to both
+                    # empty() and the scheduler — unfinished_tasks is the
+                    # counter that still sees it (seq-reset guard)
+                    for _ in run:
+                        self._handoff.task_done()
+            if token is not None:
                 try:
                     self._barrier()
                 except Exception as e:
@@ -1793,20 +1850,7 @@ class ShardedEmbeddingServer:
                     # guard reads unfinished_tasks right after a drain's
                     # barrier returns, and this token must not count
                     self._handoff.task_done()
-                    done.set()
-                continue
-            _, table, seq, query_list = item
-            try:
-                self._ingest(table, seq, query_list)
-            except Exception as e:
-                # the batch is already requeued; surface the failure at
-                # the caller's next submit()/drain() (retry contract)
-                self._stash_driver_error(e)
-            finally:
-                # a popped-but-unprocessed item is invisible to both
-                # empty() and the scheduler — unfinished_tasks is the
-                # counter that still sees it (seq-reset guard)
-                self._handoff.task_done()
+                    token[1].set()
 
     def _wait_for_item(self):
         """Blocks up to 5 ms on the empty hand-off queue for the next
@@ -1924,7 +1968,7 @@ class ShardedEmbeddingServer:
             self._driver.join(timeout=self._CLOSE_JOIN_S)
             leaked = self._driver.is_alive()
             self._driver = None
-        pushed_back = 0
+        backlog: List[tuple] = []
         if self._handoff is not None:
             # drain until no producer is still inside put(): every get
             # below frees a slot, so a submitter blocked on the full
@@ -1945,10 +1989,9 @@ class ShardedEmbeddingServer:
                     # observed gone)
                     item[1].set()
                 else:
-                    _, table, seq, query_list = item
-                    self.scheduler.push(table, seq, query_list)
-                    pushed_back += 1
+                    backlog.append(item[1:])
             self._handoff = None
+            self.scheduler.push_many(backlog)
         if self.scheduler is not None:
             requeued = self.scheduler.pending_total()
         else:
@@ -1957,7 +2000,7 @@ class ShardedEmbeddingServer:
                 requeued = self._buffered
         unserved = {
             "requeued": requeued,
-            "handoff_pushed_back": pushed_back,
+            "handoff_pushed_back": len(backlog),
             "in_flight": len(self._in_flight),
             "host_pending": (len(self._host_queue)
                              if self._host_queue is not None else 0),

@@ -4,6 +4,7 @@ stamps a reader takes per ``(producer, table, local seq)``, the plan
 build's stage timings, and the spans each flush opens on the engine."""
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -82,13 +83,13 @@ def test_a_full_handoff_queue_is_timed_as_blocked():
     block on the full queue, and that time is counted apart."""
     srv = _server(batch_size=4)
     srv.policy.handoff_depth = 1
-    real = srv._ingest
+    real = srv._ingest_many
 
-    def slow(*args):
+    def slow(*args, **kw):
         threading.Event().wait(0.002)
-        real(*args)
+        real(*args, **kw)
 
-    srv._ingest = slow
+    srv._ingest_many = slow
     try:
         _submit(srv, 1, 24)
         srv.drain()
@@ -254,3 +255,153 @@ def test_each_flush_opens_its_spans_on_the_engine(tmp_path):
     assert len(events["recross.barrier"]) >= 1
     # all on one thread's line: the driver's
     assert len({line for evs in events.values() for line, _ in evs}) == 1
+
+
+# ---------------------------------------------- chunked routing (§7.2) --
+
+
+def _held(srv):
+    """Holds the driver inside its first run until the returned event is
+    set, so the producer fills the hand-off queue meanwhile."""
+    release = threading.Event()
+    real = srv._ingest_many
+
+    def held(*args, **kw):
+        release.wait(10.0)
+        real(*args, **kw)
+
+    srv._ingest_many = held
+    return release
+
+
+def _stream(n, seed=400):
+    return [("ab"[i % 3 % 2], q) for i, q in
+            enumerate(zipf_queries(ROWS, n, 5.0, seed=seed))]
+
+
+def test_full_handoff_routes_in_chunks_like_the_inline_engine():
+    """A driver that finds a full hand-off routes runs of bags at once,
+    and serves the same rows, flushes and per-home flush counts as the
+    inline engine routing bag by bag."""
+    stream = _stream(120)
+    inline = _server(threaded=False, batch_size=16)
+    threaded = _server(batch_size=16)
+    release = _held(threaded)
+    try:
+        for srv in (inline, threaded):
+            for table, q in stream:
+                srv.submit(table, q)
+        release.set()
+        want, got = inline.drain(), threaded.drain()
+        assert sorted(got) == sorted(want)
+        for table in want:
+            np.testing.assert_array_equal(np.asarray(got[table]),
+                                          np.asarray(want[table]))
+        assert threaded.stats.batches == inline.stats.batches
+        assert threaded.stats.shard_flushes == inline.stats.shard_flushes
+        st = threaded.stats.summary()
+        assert st["routed"] == len(stream)
+        assert st["route_chunks"] < st["routed"]
+        assert st["routed"] / st["route_chunks"] > 1.0
+        assert inline.stats.route_chunks == inline.stats.routed == len(stream)
+    finally:
+        inline.close()
+        threaded.close()
+
+
+def test_chunk_route_time_leaves_out_the_flushes_it_triggers():
+    """Flushes triggered inside a run, made 20 ms slower each, stay out
+    of route_s on the driver thread too."""
+    srv = _server(batch_size=8)
+    release = _held(srv)
+    real = srv._flush_home
+    calls = []
+
+    def slow(*args, **kw):
+        calls.append(1)
+        threading.Event().wait(0.02)
+        real(*args, **kw)
+
+    srv._flush_home = slow
+    try:
+        for table, q in _stream(60):
+            srv.submit(table, q)
+        release.set()
+        srv.drain()
+        st = srv.stats
+        assert st.routed == 60 and st.route_chunks < 60 and len(calls) > 2
+        assert 0.0 < st.route_s < 0.02 * len(calls)
+    finally:
+        srv.close()
+
+
+def test_barrier_token_inside_a_run_keeps_its_fifo_place():
+    """A drain() posted between two runs of queued bags serves exactly
+    the bags before it; the bags after it wait for the next drain."""
+    from repro.core.reduction import reduce_dense_oracle
+
+    srv = _server(batch_size=16)
+    release = _held(srv)
+    first, second = _stream(40), _stream(30, seed=401)
+    try:
+        for table, q in first:
+            srv.submit(table, q)
+        out = {}
+        drainer = threading.Thread(target=lambda: out.update(srv.drain()))
+        drainer.start()
+        deadline = time.monotonic() + 10.0
+        while not any(item[0] == "barrier"  # the token is queued
+                      for item in list(srv._handoff.queue)):
+            assert time.monotonic() < deadline
+            threading.Event().wait(0.001)
+        for table, q in second:
+            srv.submit(table, q)
+        release.set()
+        drainer.join(10.0)
+        assert not drainer.is_alive()
+        for part, rows in ((first, out), (second, srv.drain())):
+            for table in "ab":
+                qs = [q for t, q in part if t == table]
+                np.testing.assert_array_equal(
+                    np.asarray(rows[table]),
+                    np.asarray(reduce_dense_oracle(TABLES[table], qs)))
+        assert srv._handoff.unfinished_tasks == 0
+    finally:
+        srv.close()
+
+
+def test_a_compile_fault_inside_a_run_is_requeued_and_surfaced():
+    """Under the legacy policy a compile failure on the driver, inside a
+    run, requeues its batch, routes the rest of the run, marks every
+    item done and surfaces at the next drain, which then serves all."""
+    from repro.core.reduction import reduce_dense_oracle
+
+    srv = _server(batch_size=8, retry=RetryPolicy.legacy())
+    release = _held(srv)
+    real = srv._compile_and_dispatch
+    calls = {"n": 0}
+
+    def flaky(entries, participants):
+        calls["n"] += 1
+        if calls["n"] == 2:
+            raise RuntimeError("transient compile error")
+        return real(entries, participants)
+
+    srv._compile_and_dispatch = flaky
+    stream = _stream(48)
+    try:
+        for table, q in stream:
+            srv.submit(table, q)
+        release.set()
+        with pytest.raises(RuntimeError, match="transient compile error"):
+            srv.drain()
+        assert srv._handoff.unfinished_tasks == 0
+        assert srv.stats.routed == len(stream)
+        rows = srv.drain()
+        for table in "ab":
+            qs = [q for t, q in stream if t == table]
+            np.testing.assert_array_equal(
+                np.asarray(rows[table]),
+                np.asarray(reduce_dense_oracle(TABLES[table], qs)))
+    finally:
+        srv.close()

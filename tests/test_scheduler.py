@@ -161,15 +161,32 @@ def test_union_tracker_matches_compiled_grid():
     sp = plan_shards([layout], [plan], 1, group_freqs=[gfreq])
     ev = zipf_queries(rows, 13, 6.0, seed=7)
     tr = BlockUnionTracker(4)
-    for q in ev:
-        rows_u = np.unique(np.asarray(q, np.int64))
-        tr.add(np.unique(layout.group_of[rows_u]).tolist())
+    groups = [np.unique(layout.group_of[np.unique(np.asarray(q, np.int64))])
+              for q in ev]
+    tr.extend(np.concatenate(groups), [g.size for g in groups])
     cq = compile_queries(layout, ev, replica_block=4)
     sbq = shard_block_queries(cq, sp, 4, participants=[0])
     assert tr.pending == len(ev)
     assert tr.grid_cells() == sbq.grid_cells_per_shard()
     tr.reset()
     assert tr.fill == 0 and tr.grid_cells() == 0
+
+
+@pytest.mark.parametrize("q_block", [1, 4, 8])
+def test_union_tracker_extend_matches_per_query_adds(q_block):
+    """extend() over runs of any length (empty queries, duplicate ids,
+    runs that open, close and straddle blocks) keeps the same fill,
+    grid and open block as the per-query set-union oracle."""
+    rng = np.random.default_rng(q_block)
+    fast, ref = BlockUnionTracker(q_block), BlockUnionTracker(q_block)
+    for _ in range(40):
+        run = [rng.integers(0, 30, int(rng.integers(0, 6))).tolist()
+               for _ in range(int(rng.integers(0, 2 * q_block + 3)))]
+        fast.extend([g for q in run for g in q], [len(q) for q in run])
+        for q in run:
+            ref._reference_add(q)
+        assert (fast.pending, fast.fill, fast.grid_cells(), fast._block) == (
+            ref.pending, ref.fill, ref.grid_cells(), ref._block)
 
 
 def test_flush_policy_validation():
@@ -1057,3 +1074,194 @@ print("OWNER_SET_THREAD_DRIVER_SHARD_MAP_OK")
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "OWNER_SET_THREAD_DRIVER_SHARD_MAP_OK" in proc.stdout
+
+
+# ------------------------------------ chunked routing ≡ per-query push --
+
+
+def _routing_server(num_shards):
+    """A two-table plan (tables of different sizes) for routing tests."""
+    tables = {"a": _int_table(160, 128, 81), "b": _int_table(96, 128, 82)}
+    histories = {"a": zipf_queries(160, 48, 5.0, seed=83),
+                 "b": zipf_queries(96, 48, 5.0, seed=84)}
+    return ShardedEmbeddingServer(
+        tables, histories, num_shards=num_shards, q_block=4,
+        group_size=16, batch_size=16, batch_size_for_eq1=512,
+        flush_policy="per-shard",
+    )
+
+
+def _mixed_stream(sched, n, seed):
+    """Seeded ``(table, seq, query)`` entries mixing replicated-only,
+    single-owner and multi-owner bags over both tables, with empty bags
+    and duplicate ids."""
+    rng = np.random.default_rng(seed)
+    by_owner = {t: {} for t in ("a", "b")}
+    for t in by_owner:
+        for r, o in enumerate(sched._owner_of_row[t]):
+            by_owner[t].setdefault(int(o), []).append(r)
+    out = []
+    for i in range(n):
+        t = "ab"[int(rng.integers(0, 2))]
+        kind = int(rng.integers(0, 5))
+        pools = by_owner[t]
+        owners = sorted(o for o in pools if o >= 0)
+        if kind == 0:
+            q = []
+        elif kind == 1 and -1 in pools:
+            q = rng.choice(pools[-1], int(rng.integers(1, 6))).tolist()
+        elif kind == 2 and owners:
+            o = owners[int(rng.integers(0, len(owners)))]
+            q = rng.choice(pools[o], int(rng.integers(1, 6))).tolist()
+            if -1 in pools:
+                q += rng.choice(pools[-1], 2).tolist()
+        else:
+            picked = rng.choice(owners, min(len(owners), int(rng.integers(2, 5))),
+                                replace=False) if owners else []
+            q = [int(rng.choice(pools[int(o)])) for o in picked]
+        if q and rng.random() < 0.3:
+            q.append(q[0])  # a duplicate id
+        out.append((t, i, [int(x) for x in q]))
+    return out
+
+
+def _route_state(sched):
+    return {
+        "pending": [(h, list(q)) for h, q in sched._pending.items()],
+        "rr": sched._rr, "tick": sched._tick,
+        "first_tick": dict(sched._first_tick),
+        "pool_owners": set(sched._pool_owners),
+        "trackers": {(h, t): (tr.pending, tr.fill, tr.grid_cells(), tr._block)
+                     for h, d in sched._trackers.items()
+                     for t, tr in d.items()},
+        "pushed": dict(sched.pushed_by_producer),
+    }
+
+
+_ROUTING_POLICIES = {
+    "per-shard": dict(kind="per-shard", batch_size=16),
+    "deadline": dict(kind="deadline", batch_size=16, deadline=6),
+    "owner-set": dict(kind="owner-set", batch_size=16),
+    "owner-set-max2": dict(kind="owner-set", batch_size=16, owner_set_max=2),
+    "union-budget": dict(kind="per-shard", batch_size=16, union_budget=12),
+}
+
+
+@pytest.mark.parametrize("num_shards", [1, 2, 4])
+@pytest.mark.parametrize("policy", sorted(_ROUTING_POLICIES))
+def test_push_many_matches_per_query_push(num_shards, policy):
+    """push_many over runs of any length gives every bag the home,
+    every home the pending order, round robin, ticks, union trackers
+    and pool owners, and every due check the homes and batches, that
+    one _reference_push and one due check per bag give."""
+    from repro.serve.scheduler import FlushScheduler
+
+    srv = _routing_server(num_shards)
+    pol = FlushPolicy.parse(FlushPolicy(**_ROUTING_POLICIES[policy]),
+                            batch_size=16)
+    ref, fast = (FlushScheduler(srv.plan, srv.layouts, srv.names, 4, pol)
+                 for _ in range(2))
+    stream = _mixed_stream(ref, 300, seed=num_shards)
+
+    def due_point(sched, log):
+        due = [(h, sched.due_reason(h)) for h in sched.due_homes()]
+        if due:
+            log.append((sched._tick, due, [sched.take(h) for h, _ in due]))
+
+    ref_homes, ref_due = [], []
+    for table, seq, query in stream:
+        ref_homes.append(ref._reference_push(table, seq, query))
+        due_point(ref, ref_due)
+    fast_homes, fast_due = [], []
+    rng, i = np.random.default_rng(7), 0
+    while i < len(stream):
+        k = int(rng.integers(1, 48))
+        fast_homes += fast.push_many(stream[i:i + k],
+                                     flush=lambda: due_point(fast, fast_due))
+        i += k
+    assert fast_homes == ref_homes
+    assert fast_due == ref_due
+    assert ref_due, "no due point: the stream exercises nothing"
+    assert _route_state(fast) == _route_state(ref)
+
+
+def test_push_many_cuts_runs_at_due_points():
+    """A run is pushed up to each due point, flushed there, and the rest
+    stays pending; without a flush callback the whole run is pushed."""
+    from repro.serve.scheduler import FlushScheduler
+
+    srv = _routing_server(1)
+    pol = FlushPolicy.parse("per-shard", batch_size=16)
+    sched = FlushScheduler(srv.plan, srv.layouts, srv.names, 4, pol)
+    stream = [("a", i, [i % 160, (7 * i) % 160]) for i in range(40)]
+    seen = []
+
+    def flush():
+        for h in sched.due_homes():
+            seen.append((sched._tick, [e[1] for e in sched.take(h)[0]]))
+
+    sched.push_many(stream, flush=flush)
+    assert seen == [(16, list(range(16))), (32, list(range(16, 32)))]
+    assert [e[1] for e in sched._pending[0]] == list(range(32, 40))
+    assert sched._first_tick == {0: 32}
+    back = FlushScheduler(srv.plan, srv.layouts, srv.names, 4, pol)
+    back.push_many(stream)
+    assert back.pending_total() == 40 and back.due_homes() == [0]
+
+
+def test_push_many_stops_wherever_due_reason_says(monkeypatch):
+    """push_many asks the policy's own due check after every bag, so a
+    trigger it does not know of (here: every seventh tick) still cuts
+    the run exactly where per-bag routing would flush."""
+    from repro.serve.scheduler import FlushScheduler
+
+    srv = _routing_server(2)
+    pol = FlushPolicy.parse("per-shard", batch_size=16)
+    sched = FlushScheduler(srv.plan, srv.layouts, srv.names, 4, pol)
+
+    def every_seventh(self, home):
+        return "tick" if self._pending[home] and self._tick % 7 == 0 else None
+
+    monkeypatch.setattr(FlushScheduler, "due_reason", every_seventh)
+    seen = []
+
+    def flush():
+        for h in sched.due_homes():
+            seen.append(sched._tick)
+            sched.take(h)
+
+    sched.push_many(_mixed_stream(sched, 30, seed=5), flush=flush)
+    assert sorted(set(seen)) == [7, 14, 21, 28]
+
+
+def test_push_many_raises_on_a_cold_bag_after_the_bags_before_it():
+    """A bag touching a cold (host-tier) group raises as per-query
+    routing does: the bags before it are pushed, it and the rest not."""
+    import types
+
+    from repro.serve.scheduler import FlushScheduler
+
+    srv = _routing_server(2)
+    sog = np.asarray(srv.plan.shard_of_group).copy()
+    gof = np.asarray(srv.layouts[srv.names.index("a")].group_of)
+    seg = next(t for t in srv.plan.tables if t.name == "a")
+    cold_row = 5
+    sog[gof[cold_row] + seg.group_offset] = -2
+    plan = types.SimpleNamespace(num_shards=2, shard_of_group=sog,
+                                 tables=srv.plan.tables)
+    pol = FlushPolicy.parse("per-shard", batch_size=16)
+    warm = [r for r in range(160) if gof[r] != gof[cold_row]]
+    stream = [("a", 0, warm[:3]), ("b", 1, [1, 2]),
+              ("a", 2, [warm[3], cold_row]), ("a", 3, warm[4:6])]
+    fast = FlushScheduler(plan, srv.layouts, srv.names, 4, pol)
+    with pytest.raises(ValueError, match="cold"):
+        fast.push_many(stream)
+    ref = FlushScheduler(plan, srv.layouts, srv.names, 4, pol)
+    for e in stream[:2]:
+        ref._reference_push(*e)
+    with pytest.raises(ValueError, match="cold"):
+        ref._reference_push(*stream[2])
+    assert _route_state(fast) == _route_state(ref)
+    assert fast.pending_total() == 2
+    with pytest.raises(ValueError, match="cold"):
+        fast.route("a", [cold_row])
