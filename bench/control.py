@@ -31,9 +31,14 @@ def reading(cell, seed: int) -> tuple:
 
     data = harness.make_data(cell.config, cell.traffic, seed, int(cell.traffic["bags"]))
     tabs, bags = cell.driver().sequence(types.SimpleNamespace(data=data, seed=seed))
-    tables = reference.make_tables(harness.table_seed(seed), data.shapes)
-    gap = max(reference.control_gap(t, [b for k, b in zip(tabs.tolist(), bags) if k == i])
-              for i, t in enumerate(tables) if np.any(tabs == i))
+    gap = 0.0
+    for i in range(len(data.names)):       # one table on the device at a time
+        if not np.any(tabs == i):
+            continue
+        table = reference.make_table(harness.table_seed(seed), data.shapes, i)
+        mine = [b for k, b in zip(tabs.tolist(), bags) if k == i]
+        gap = max(gap, reference.control_gap(table, mine))
+        del table
     return gap, len(bags)
 
 

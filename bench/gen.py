@@ -3,7 +3,8 @@
 :func:`scale_trace` and :func:`zipf_popularity` are copies of
 ``repro.data.synthetic.scale_trace`` / ``zipf_popularity``, kept here
 so that no change to the program can change the traffic the benchmark
-measures it with.  Everything is a pure function of its seed.
+measures it with; the copy adds fixed bag lengths (``fixed_len``).
+Everything is a pure function of its seed.
 """
 
 from __future__ import annotations
@@ -42,13 +43,15 @@ def scale_trace(
     in_cluster_p: float = 0.85,
     template_zipf: float = 1.1,
     seed: int = 0,
+    fixed_len: bool = False,
 ) -> List[np.ndarray]:
     """Lookup trace of ``num_queries`` bags of distinct row ids.
 
     Zipf-popular template baskets over Zipf-popular interest clusters:
     rows are ranked by global Zipf popularity and bucketed into clusters;
     every template picks a cluster by cluster popularity, draws ``1 +
-    Poisson(mean_bag - 1)`` lookups, each in-cluster with probability
+    Poisson(mean_bag - 1)`` lookups (exactly ``mean_bag`` with
+    ``fixed_len``), each in-cluster with probability
     ``in_cluster_p`` else global, then dedups; the query stream samples
     template ids from a Zipf over templates.  Queries share the template
     arrays by reference.  The template picks are drawn last, so a longer
@@ -56,6 +59,9 @@ def scale_trace(
     """
     if num_rows < 1 or num_queries < 0:
         raise ValueError("num_rows must be >= 1 and num_queries >= 0")
+    if fixed_len and (mean_bag < 1 or mean_bag != int(mean_bag)):
+        raise ValueError(f"a fixed bag length must be a whole number >= 1, "
+                         f"not {mean_bag}")
     rng = np.random.default_rng(seed)
     nt = num_templates or max(64, num_rows // 64)
     if not num_clusters:
@@ -89,7 +95,10 @@ def scale_trace(
     tpl_c = tpl_c[cl_size[tpl_c] > 0]
     nt = tpl_c.size
 
-    lens = 1 + rng.poisson(max(mean_bag - 1.0, 0.0), size=nt)
+    if fixed_len:
+        lens = np.full(nt, int(mean_bag), dtype=np.int64)
+    else:
+        lens = 1 + rng.poisson(max(mean_bag - 1.0, 0.0), size=nt)
     tid = np.repeat(np.arange(nt, dtype=np.int64), lens)
     total = int(lens.sum())
     c_of_draw = tpl_c[tid]
@@ -130,8 +139,14 @@ def scale_trace(
 def table_bags(table: Dict, history: int, n: int, seed: int) -> List[np.ndarray]:
     """``history + n`` bags of one table's generator (``table`` holds the
     config's ``rows``, ``mean_bag``, ``zipf_a``, ``in_cluster_p``,
-    ``num_clusters``, ``rows_per_template`` and ``template_zipf``)."""
+    ``num_clusters``, ``rows_per_template`` and ``template_zipf``, and
+    may set ``"bag_len": "fixed"``: every template then draws exactly
+    ``mean_bag`` ids before its repeats are dropped, as a table of fixed
+    multi-hot size does)."""
     rows = int(table["rows"])
+    fixed = "bag_len" in table
+    if fixed and table["bag_len"] != "fixed":
+        raise ValueError(f"bag_len can only be 'fixed', not {table['bag_len']!r}")
     return scale_trace(
         rows, history + n, float(table["mean_bag"]),
         num_templates=max(64, rows // int(table["rows_per_template"])),
@@ -140,6 +155,7 @@ def table_bags(table: Dict, history: int, n: int, seed: int) -> List[np.ndarray]
         in_cluster_p=float(table["in_cluster_p"]),
         template_zipf=float(table["template_zipf"]),
         seed=seed,
+        fixed_len=fixed,
     )
 
 

@@ -7,12 +7,14 @@ traffic mix in ``bench/traffic/<traffic>.json``, the mix's driver in
 ``bench/metrics/<name>.py``.  A new cell, configuration, mix or metric is
 new files and entries, never an edit here.
 
-The run (:func:`run`) makes the tables on the device from the seed and the
-plan history and request bags from :mod:`bench.gen`, builds the server
-(timed as the plan build), lets the driver warm every flush shape its
-traffic uses, measures the driver's window, reads the device's memory
-peak, closes the server, and only then checks every bag of the window
-against :mod:`bench.reference`.
+The run (:func:`run`) makes the tables on the device from the seed, one at
+a time, each copied to the host and freed before the next, and the plan
+history and request bags from :mod:`bench.gen`; builds the server (timed
+as the plan build) on a mesh of the cell's chips where it has more than
+one; lets the driver warm every flush shape its traffic uses, measures
+the driver's window, reads every device's memory peak, closes the
+server, and only then checks every bag of the window against
+:mod:`bench.reference`, table by table.
 """
 
 from __future__ import annotations
@@ -276,6 +278,48 @@ def server_kwargs(cell: Cell) -> dict:
     return kw
 
 
+def shards_of(cell: Cell) -> int:
+    """The server's ``num_shards``, which has to be the cell's chips: one
+    shard per chip of the mesh, or one shard on one chip."""
+    shards = int(server_kwargs(cell).get("num_shards", 1))
+    if shards != cell.chips:
+        raise ValueError(f"cell {cell.name!r} asks for {cell.chips} chips but "
+                         f"its server has num_shards {shards}; they must agree")
+    return shards
+
+
+def make_mesh(devices):
+    """The serving mesh over ``devices`` on its ``model`` axis (the
+    server's default ``axis_name``), or ``None`` for one device, which
+    the server then serves without ``shard_map``."""
+    if len(devices) == 1:
+        return None
+    import jax
+
+    return jax.make_mesh((1, len(devices)), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                         devices=devices)
+
+
+def host_tables(seed: int, data: Data) -> Dict[str, np.ndarray]:
+    """Every table of the run on the host: each made on the device, copied
+    and freed before the next is made."""
+    from bench import reference
+
+    host = {}
+    for i, name in enumerate(data.names):
+        table = reference.make_table(table_seed(seed), data.shapes, i)
+        host[name] = np.asarray(table)
+        del table
+    return host
+
+
+def memory_peaks(devices) -> List[int]:
+    """Each device's peak bytes in use (0 where the backend keeps none)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
+
+
 def stats_snapshot(stats) -> dict:
     return {
         "batches": stats.batches,
@@ -297,16 +341,14 @@ class Check:
 def check(window: Window, data: Data, seed: int, report: dict,
           limit: float) -> Check:
     """Every bag of the window against the reference; the tables are
-    made again from the seed, nothing is taken from the program."""
-    import jax
-
+    made again from the seed, one at a time, each freed before the next;
+    nothing is taken from the program."""
     from bench import reference
 
-    tables = reference.make_tables(table_seed(seed), data.shapes)
-    by_name = dict(zip(data.names, tables))
+    index = {name: i for i, name in enumerate(data.names)}
     gap = 0.0
     missing = 0
-    for table, bags, served in window.groups:
+    for name, bags, served in window.groups:
         served = (np.zeros((0, data.shapes[0][1]), np.float32)
                   if served is None else np.asarray(served, dtype=np.float32))
         if served.shape[0] != len(bags):
@@ -315,8 +357,9 @@ def check(window: Window, data: Data, seed: int, report: dict,
         if not np.all(np.isfinite(served)):
             gap = math.inf
             continue
-        gap = max(gap, reference.max_gap(by_name[table], bags, served))
-    del tables, by_name
+        table = reference.make_table(table_seed(seed), data.shapes, index[name])
+        gap = max(gap, reference.max_gap(table, bags, served))
+        del table
     faults = report["serve"]["faults"]
     failed = (missing + len(faults["quarantined"]) + faults["degraded_flushes"]
               + report["serve"]["tiers"]["host_flushes"])
@@ -334,6 +377,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     err = sys.stderr
     import jax
 
+    shards = shards_of(cell)
     if need_chip:
         devices = find_chips(cell.chips)
         peaks = peaks_for(devices[0].device_kind, cell.root)
@@ -355,20 +399,22 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     t0 = time.perf_counter()
     n_bags = int(traffic["bags"])
     data = make_data(config, traffic, seed, n_bags)
-    tables = reference.make_tables(table_seed(seed), data.shapes)
-    host = {n: np.asarray(t) for n, t in zip(data.names, tables)}
-    del tables
+    host = host_tables(seed, data)
     print(f"data: {len(data.names)} tables, {sum(s[0] for s in data.shapes)} "
           f"rows x {data.shapes[0][1]}, {sum(h.nbytes for h in host.values()) / 2**30:.3f}"
           f" GiB, {n_bags} bags made in "
           f"{time.perf_counter() - t0:.3f} s", file=err)
 
     t0 = time.perf_counter()
-    server = ShardedEmbeddingServer(host, data.histories, **server_kwargs(cell))
+    mesh = make_mesh(devices)
+    server = ShardedEmbeddingServer(host, data.histories, mesh=mesh,
+                                    **server_kwargs(cell))
     jax.block_until_ready(server.shard_images)
     plan_build_s = time.perf_counter() - t0
     print(f"plan build + image placement: {plan_build_s!r} s "
-          f"({server.shard_images.shape[1]} tiles per shard)", file=err)
+          f"({shards} shard(s) of {server.shard_images.shape[1]} tiles, "
+          f"{'no mesh' if mesh is None else f'mesh {dict(mesh.shape)}'})",
+          file=err)
 
     session = Session(cell, seed, data, server, trace, log)
     driver = cell.driver()
@@ -392,8 +438,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     window_compiles = log.count() - compiles0
     after = stats_snapshot(stats)
     setup_s = window.t_first - t_process
-    mem = device.memory_stats() or {}
-    memory_peak = int(mem.get("peak_bytes_in_use", 0))
+    memory_peak = memory_peaks(devices)
     server.close()
     report = server.report()
     host_compile = after["host_compile_s"] - before["host_compile_s"]
@@ -415,9 +460,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         window_compiles=window_compiles, window=window, peaks=peaks,
         dim=data.shapes[0][1], itemsize=4,
         tile_rows=int(config["server"]["group_size"]),
+        chips=len(devices), shards=shards,
     )
     out_device = {"platform": device.platform, "kind": device.device_kind,
-                  "count": len(devices), "memory_peak_bytes": memory_peak}
+                  "count": len(devices), "memory_peak_bytes": max(memory_peak),
+                  "memory_peak_bytes_per_device": memory_peak}
     metrics: Dict[str, dict] = {}
     breakdown = None
     if trace:
@@ -435,8 +482,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         breakdown = {"device_ops": summary.device_ops,
                      "idle_gaps": summary.idle_gaps}
         print(f"trace: busy {summary.busy_s!r} s of {summary.window_s!r} s, "
-              f"kernel {summary.kernel_s!r} s in {summary.kernel_events} events",
-              file=err)
+              f"kernel {summary.kernel_s!r} s in {summary.kernel_events} events, "
+              f"collectives {summary.collective_s!r} s (per device)", file=err)
         baseline = gather_baseline(window, data, seed, measured)
         if baseline:
             print(baseline, file=err)
@@ -478,6 +525,8 @@ class Measured:
     dim: int
     itemsize: int
     tile_rows: int
+    chips: int                   # devices the window ran on
+    shards: int                  # the server's num_shards, one grid each
     trace: object = None         # bench.trace.Summary of a traced run
     needed_bytes: Optional[int] = None
 
@@ -489,7 +538,10 @@ class Measured:
 def gather_baseline(window: Window, data: Data, seed: int, m: Measured) -> str:
     """Times the reference's gather-and-sum over the window's bags and
     gives its share of the HBM roofline beside the kernel's (the plain
-    XLA gather baseline; printed, not a metric)."""
+    XLA gather baseline; printed, not a metric).  The gather runs one
+    table at a time on one device, so its wall is the sum of the tables'
+    walls and its bound one chip's; the kernel's share is bounded by all
+    the cell's chips, as ``kernel_roofline_pct`` is."""
     import jax
 
     from bench import reference
@@ -497,22 +549,23 @@ def gather_baseline(window: Window, data: Data, seed: int, m: Measured) -> str:
     if window.flushes is None or m.needed_bytes is None or m.peaks is None:
         return ""
     tabs, bags = window.flushes
-    tables = reference.make_tables(table_seed(seed), data.shapes)
-    work = []
-    for i, table in enumerate(tables):
+    wall = 0.0
+    for i in range(len(data.names)):
         mine = [b for t, b in zip(tabs.tolist(), bags) if t == i]
-        if mine:
-            length = reference.pad_len(mine)
-            blocks = [(table, jax.device_put(ids), jax.device_put(mask))
-                      for _, ids, mask in reference.blocks(mine, length)]
-            jax.block_until_ready(reference.block_rows(*blocks[0]))  # compile
-            work += blocks
-    t0 = time.perf_counter()
-    jax.block_until_ready([reference.block_rows(*w) for w in work])
-    wall = time.perf_counter() - t0
-    bound = m.needed_bytes / (m.peaks["hbm_bytes_per_s"])
+        if not mine:
+            continue
+        table = reference.make_table(table_seed(seed), data.shapes, i)
+        length = reference.pad_len(mine)
+        work = [(table, jax.device_put(ids), jax.device_put(mask))
+                for _, ids, mask in reference.blocks(mine, length)]
+        jax.block_until_ready(reference.block_rows(*work[0]))  # compile
+        t0 = time.perf_counter()
+        jax.block_until_ready([reference.block_rows(*w) for w in work])
+        wall += time.perf_counter() - t0
+        del table, work
+    bound = m.needed_bytes / m.peaks["hbm_bytes_per_s"]
     kernel = ("not measured" if not (m.trace and m.trace.kernel_s)
-              else f"{100 * bound / m.trace.kernel_s!r} %")
+              else f"{100 * bound / m.chips / m.trace.kernel_s!r} %")
     return (f"gather baseline: reference gather-and-sum over the window's "
             f"{len(bags)} bags in {wall!r} s (host clock, device work only),"
             f" HBM roofline share {100 * bound / wall!r} %; crossbar kernel "

@@ -1,6 +1,8 @@
-"""Kernel: bytes of tiles the crossbar's grid fetches in the window
-(``stats.grid_cells_per_shard`` times one tile) over the bytes the bags
-require (:func:`bench.reference.needed_bytes`)."""
+"""Kernel: bytes of tiles the crossbar's grids fetch in the window over
+the bytes the bags require (:func:`bench.reference.needed_bytes`).
+Every shard runs a grid of ``stats.grid_cells_per_shard`` cells per
+flush, so the tiles fetched are ``shards`` times those cells times one
+tile."""
 
 UNIT = "x"
 LAYER = "kernel (kernels/crossbar_reduce)"
@@ -11,4 +13,4 @@ def read(m):
     if not m.needed_bytes:
         return None
     cells = m.after["grid_cells"] - m.before["grid_cells"]
-    return cells * m.tile_rows * m.dim * m.itemsize / m.needed_bytes
+    return m.shards * cells * m.tile_rows * m.dim * m.itemsize / m.needed_bytes

@@ -2,10 +2,11 @@
 
 A bag's answer is the sum of the table rows its distinct ids name.  The
 reference computes exactly that, with nothing of the program: the table
-is made again from the seed (:func:`make_tables`), the bags are padded to
-fixed-shape blocks of ``BLOCK_BAGS`` bags by a bag length rounded up to
-``LEN_STEP`` (one compile per table of a cell, not one per bag length),
-and each block is a gather and an f32 sum on the device.
+is made again from the seed, one table at a time (:func:`make_table`),
+the bags are padded to fixed-shape blocks of ``BLOCK_BAGS`` bags by a
+bag length rounded up to ``LEN_STEP`` (one compile per table of a cell,
+not one per bag length), and each block is a gather and an f32 sum on
+the device.
 
 The compared number is the widest gap, over every element of every bag
 of the window, between the served row and the reference row
@@ -39,20 +40,21 @@ def key_for(seed: int) -> jax.Array:
     return jax.random.wrap_key_data(jnp.asarray(state, dtype=jnp.uint32))
 
 
-@functools.partial(jax.jit, static_argnames=("shapes", "dtype"))
-def _tables(key, shapes, dtype):
-    keys = jax.random.split(key, len(shapes))
-    return tuple(
-        jax.random.normal(k, shape, dtype=jnp.float32).astype(dtype)
-        for k, shape in zip(keys, shapes)
-    )
+@functools.partial(jax.jit, static_argnames=("index", "count", "shape", "dtype"))
+def _table(key, index, count, shape, dtype):
+    k = jax.random.split(key, count)[index]
+    return jax.random.normal(k, shape, dtype=jnp.float32).astype(dtype)
 
 
-def make_tables(seed: int, shapes: Sequence[tuple], dtype=jnp.float32):
-    """Every table of a configuration, N(0, 1) values, on the device, in
-    one jitted call from the seed."""
-    return _tables(key_for(seed), tuple(tuple(s) for s in shapes),
-                   jnp.dtype(dtype).name)
+def make_table(seed: int, shapes: Sequence[tuple], index: int,
+               dtype=jnp.float32) -> jax.Array:
+    """Table ``index`` of a configuration whose tables have ``shapes``:
+    N(0, 1) values from the seed, made on the device in a jitted call of
+    its own.  Table ``i`` is ``normal(split(key, len(shapes))[i], shape)``,
+    so a caller that makes, uses and frees one table at a time holds no
+    more device memory than the largest table needs."""
+    return _table(key_for(seed), int(index), len(shapes),
+                  tuple(shapes[index]), jnp.dtype(dtype).name)
 
 
 def round_bf16(x: jax.Array) -> jax.Array:

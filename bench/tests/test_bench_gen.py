@@ -1,6 +1,7 @@
 """The traffic generator is a pure function of its seed, and the loader
 finds cells, configurations, mixes and metrics added only as files."""
 
+import hashlib
 import json
 
 import numpy as np
@@ -37,6 +38,48 @@ def test_scale_trace_matches_the_program_generator_it_copies():
     kw = dict(zipf_a=1.2, num_clusters=None, in_cluster_p=0.85, seed=3)
     assert _same(gen.scale_trace(5000, 400, 12.0, **kw),
                  scale_trace(5000, 400, 12.0, **kw))
+
+
+def _digest(bags):
+    h = hashlib.sha256()
+    for bag in bags:
+        h.update(np.asarray(bag, dtype=np.int64).tobytes())
+        h.update(b"|")
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("config, table, digest", [
+    ("amazon-automotive", "automotive",
+     "afff2e96c4ddf58080d9386063e667ca31a00568998ea5cea3b39545603bf931"),
+    ("amazon-tablei5", "sports",
+     "835c4347e3331cddd019555663d74f9aab7d4b65c5cf566459920f0965b55d72"),
+])
+def test_bags_without_bag_len_are_unchanged(config, table, digest):
+    """The digests were taken before ``bag_len`` existed: a table that
+    does not set it draws the very same bags."""
+    t = json.loads((harness.ROOT / "bench" / "configs" / f"{config}.json")
+                   .read_text())["tables"][table]
+    assert "bag_len" not in t
+    assert _digest(gen.table_bags(t, 1000, 2000, 2**33 + 5)) == digest
+
+
+@pytest.mark.parametrize("size", [1, 3, 12, 100])
+def test_fixed_bag_lengths_are_mean_bag_or_less(size):
+    fixed = dict(TABLE, mean_bag=size, bag_len="fixed")
+    bags = gen.table_bags(fixed, 100, 2000, 2**31 + 9)
+    lens = np.array([len(b) for b in bags])
+    # each template draws ``size`` ids; repeats among them are dropped
+    assert lens.min() >= 1 and lens.max() <= size
+    assert size > 1 or np.all(lens == 1)
+    assert _same(bags, gen.table_bags(fixed, 100, 2000, 2**31 + 9))
+
+
+@pytest.mark.parametrize("bad", [dict(mean_bag=2.5, bag_len="fixed"),
+                                 dict(mean_bag=0, bag_len="fixed"),
+                                 dict(bag_len="uniform")])
+def test_bad_bag_lengths_are_refused(bad):
+    with pytest.raises(ValueError):
+        gen.table_bags(dict(TABLE, **bad), 10, 10, 1)
 
 
 def test_table_order_gives_each_request_one_bag_per_table():

@@ -1,5 +1,9 @@
 """The plain reference against NumPy in float64, and its control."""
 
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,18 +22,41 @@ def _f64(table, bags):
 
 
 def test_tables_are_made_from_the_seed():
-    (a,) = reference.make_tables(2**40 + 1, [(300, 128)])
-    (b,) = reference.make_tables(2**40 + 1, [(300, 128)])
-    (c,) = reference.make_tables(2**40 + 2, [(300, 128)])
+    a = reference.make_table(2**40 + 1, [(300, 128)], 0)
+    b = reference.make_table(2**40 + 1, [(300, 128)], 0)
+    c = reference.make_table(2**40 + 2, [(300, 128)], 0)
     assert a.dtype == np.float32 and a.shape == (300, 128)
     assert np.array_equal(np.asarray(a), np.asarray(b))
     assert not np.array_equal(np.asarray(a), np.asarray(c))
 
 
+@functools.partial(jax.jit, static_argnames=("shapes", "dtype"))
+def _all_tables_in_one_call(key, shapes, dtype):
+    """The maker before tables were made one at a time, kept as the
+    oracle of :func:`reference.make_table`."""
+    keys = jax.random.split(key, len(shapes))
+    return tuple(
+        jax.random.normal(k, shape, dtype=jnp.float32).astype(dtype)
+        for k, shape in zip(keys, shapes)
+    )
+
+
+@pytest.mark.parametrize("seed", [0, 5, 2**40 + 7])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_tables_one_at_a_time_equal_the_one_call_maker(seed, dtype):
+    shapes = ((300, 128), (17, 128), (1000, 256), (300, 128))
+    want = _all_tables_in_one_call(reference.key_for(seed), shapes, dtype)
+    for i, table in enumerate(want):
+        got = reference.make_table(seed, list(shapes), i, dtype=dtype)
+        assert got.dtype == table.dtype and got.shape == table.shape
+        assert np.array_equal(np.asarray(got).view(np.uint8),
+                              np.asarray(table).view(np.uint8))
+
+
 @pytest.mark.parametrize("n", [1, 37, 1500])
 def test_reference_matches_numpy_float64(n):
     rng = np.random.default_rng(n)
-    (table,) = reference.make_tables(n, [(2000, 128)])
+    table = reference.make_table(n, [(2000, 128)], 0)
     bags = [rng.integers(0, 2000, size=rng.integers(0, 90)) for _ in range(n)]
     bags[0] = np.array([5, 5, 7, 5])             # repeated ids count once
     want = _f64(table, bags)
@@ -57,7 +84,7 @@ def test_control_fails_the_limit_and_the_reference_passes_it():
     limit = harness.load_cell("automotive.batch").max_gap_limit
     bags = gen.table_bags(AUTOMOTIVE_LIKE, 0, 3000, 5)
     for seed in (1, 2, 3):
-        (table,) = reference.make_tables(seed, [(50000, 128)])
+        table = reference.make_table(seed, [(50000, 128)], 0)
         assert reference.control_gap(table, bags) > limit
         ref = np.concatenate([np.asarray(r) for r in
                               reference.reference_rows(table, bags)])[:len(bags)]

@@ -1,6 +1,8 @@
 """A tiny copy of the benchmark for tests on the CPU: the real ``bench``
 files under a temporary root, with small configurations and their batch
-cells added as new files and entries."""
+cells added as new files and entries.  ``tiny4.batch`` asks for four
+chips and four shards: it runs where JAX sees four devices (on the CPU,
+``--xla_force_host_platform_device_count=4``)."""
 
 from __future__ import annotations
 
@@ -42,10 +44,14 @@ def make_root(tmp: Path) -> Path:
     (tmp / "bench" / "traffic" / "tiny_drifted.json").write_text(json.dumps(TINY_DRIFTED))
     single = dict(TINY_CONFIG, tables={"a": TINY_CONFIG["tables"]["a"]})
     (tmp / "bench" / "configs" / "tiny1.json").write_text(json.dumps(single))
+    four = dict(TINY_CONFIG, server=dict(TINY_CONFIG["server"], num_shards=4))
+    (tmp / "bench" / "configs" / "tiny4.json").write_text(json.dumps(four))
     bench["configs"] += [
         {"name": "tiny", "source": "test", "file": "bench/configs/tiny.json",
          "reduced": [], "why": "test"},
         {"name": "tiny1", "source": "test", "file": "bench/configs/tiny1.json",
+         "reduced": [], "why": "test"},
+        {"name": "tiny4", "source": "test", "file": "bench/configs/tiny4.json",
          "reduced": [], "why": "test"},
     ]
     bench["workloads"] += [
@@ -55,9 +61,11 @@ def make_root(tmp: Path) -> Path:
          "chips": 1, "why": "test"},
         {"name": "tiny.drifted", "config": "tiny", "traffic": "tiny_drifted",
          "chips": 1, "why": "test"},
+        {"name": "tiny4.batch", "config": "tiny4", "traffic": "tiny_batch",
+         "chips": 4, "why": "test"},
     ]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m and "bags_per_s" in (m["name"], m.get("moves")):
-            m["workloads"] += ["tiny.batch", "tiny.drifted"]
+            m["workloads"] += ["tiny.batch", "tiny.drifted", "tiny4.batch"]
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
     return tmp

@@ -11,7 +11,10 @@ Python tracer off, so the host's own work is not slowed by it);
   operation ran on a device (:data:`BUSY_LINES`), inside that window,
   averaged over the devices traced;
 * ``kernel_s``: the summed device time of the Pallas kernels (the ops
-  :func:`is_kernel` accepts) inside the window;
+  :func:`is_kernel` accepts) inside the window, averaged over the
+  devices traced;
+* ``collective_s``: the same for the collective operations between
+  chips (the ops :func:`is_collective` accepts); 0 on one chip;
 * ``device_ops``: the ten device operations that took most time, by
   :func:`short_name`;
 * ``idle_gaps``: idle device time inside the window, summed by what the
@@ -86,12 +89,25 @@ def is_kernel(name: str, stats: Dict[str, object]) -> bool:
     return "custom-call" in text or "custom_call" in text or "mosaic" in text
 
 
+#: collective operations between devices, and their asynchronous halves
+#: (``all-reduce-start``, ``all-gather-done``, ...)
+_COLLECTIVE = re.compile(r"(all-reduce|reduce-scatter|all-gather|collective-permute)")
+
+
+def is_collective(name: str) -> bool:
+    """A collective between devices: an operation whose name or opcode
+    (:func:`short_name`) is an all-reduce, reduce-scatter, all-gather or
+    collective-permute, or the ``-start`` or ``-done`` half of one."""
+    return _COLLECTIVE.search(short_name(name)) is not None
+
+
 @dataclasses.dataclass
 class Summary:
     window_s: float
     busy_s: float
     kernel_s: float
     kernel_events: int
+    collective_s: float
     device_ops: List[list]
     idle_gaps: List[list]
 
@@ -158,6 +174,7 @@ def reduce(path: Path, window: str = "bench.window",
     busy = []
     kernel_ns = 0.0
     kernel_events = 0
+    collective_ns = 0.0
     per_op: Dict[str, float] = {}
     gaps_ns: Dict[str, float] = {}
     host_iv = [(n, s, e) for n, s, e in host if n != window and e > w0 and s < w1]
@@ -175,6 +192,8 @@ def reduce(path: Path, window: str = "bench.window",
             if kernel(name, st):
                 kernel_ns += d
                 kernel_events += 1
+            elif is_collective(name):
+                collective_ns += d
         edges = np.concatenate([[w0], merged.reshape(-1), [w1]]).reshape(-1, 2)
         for g0, g1 in edges:
             if g1 <= g0:
@@ -195,6 +214,7 @@ def reduce(path: Path, window: str = "bench.window",
         busy_s=sum(busy) / n / 1e9,
         kernel_s=kernel_ns / n / 1e9,
         kernel_events=kernel_events,
+        collective_s=collective_ns / n / 1e9,
         device_ops=[[k, v / n / 1e9] for k, v in top],
         idle_gaps=[[k, float(v) / n / 1e9] for k, v in gaps],
     )
