@@ -46,14 +46,17 @@ from repro.core.reduction import (
     BlockedQueries,
     BlockUnionTracker,
     CompiledQueries,
+    FusedActivations,
     ShardedBlockedQueries,
     block_compiled_queries,
     compile_queries,
     concat_compiled_queries,
+    fuse_activations,
     fused_group_loads,
     offset_compiled_queries,
     reduce_dense_oracle,
     reduce_via_layout,
+    shard_block_activations,
     shard_block_queries,
 )
 from repro.core import baselines
@@ -72,10 +75,10 @@ __all__ = [
     "SimReport", "simulate_batch", "simulate_cpu_baseline",
     "simulate_nmars_baseline",
     "BlockedQueries", "BlockUnionTracker", "CompiledQueries",
-    "ShardedBlockedQueries",
+    "FusedActivations", "ShardedBlockedQueries",
     "block_compiled_queries", "compile_queries", "concat_compiled_queries",
-    "fused_group_loads",
+    "fuse_activations", "fused_group_loads",
     "offset_compiled_queries", "reduce_dense_oracle", "reduce_via_layout",
-    "shard_block_queries",
+    "shard_block_activations", "shard_block_queries",
     "baselines",
 ]

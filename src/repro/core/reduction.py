@@ -19,6 +19,11 @@ representation so everything jits):
   ``bitmaps``   (batch, max_tiles, tile_rows) activation masks (0/1)
 
 produced by :func:`compile_queries` from the ragged host-side form.
+
+The server's flush skips that dense form: :func:`fuse_activations` joins
+each table's sparse :class:`~repro.core.mapping.ActivationSet` in the
+fused tile space and :func:`shard_block_activations` builds the per-shard
+blocked schedule straight from it, in NumPy.
 """
 
 from __future__ import annotations
@@ -31,7 +36,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.mapping import CrossbarLayout
+from repro.core.mapping import ActivationSet, CrossbarLayout
 
 
 @dataclasses.dataclass
@@ -125,8 +130,8 @@ def _pad_to_blocks(
 ) -> tuple[np.ndarray, np.ndarray, int]:
     """Zero/-1-pads a flat compiled batch up to a q_block multiple.
 
-    Shared by every block compiler (flat and sharded) so the padding
-    rule — and therefore output row alignment — can never diverge.
+    The padding rule of every block compiler: ``ceil(batch / q_block)``
+    blocks, so output rows align whichever compiler built them.
     """
     batch, s_flat = ids.shape
     tile_rows = bms.shape[-1]
@@ -239,6 +244,8 @@ class ShardedBlockedQueries:
     single-device blocked reduction exactly once per activation.
     """
 
+    # device arrays from shard_block_queries, NumPy from
+    # shard_block_activations (the flush's schedule before its one put)
     tile_ids: jax.Array   # (P, nb, max_tiles) int32 shard-LOCAL ids, -1 pad
     bitmaps: jax.Array    # (P, nb, max_tiles, q_block, tile_rows)
     q_block: int
@@ -277,6 +284,34 @@ class ShardedBlockedQueries:
         return self.num_blocks * self.max_tiles
 
 
+@dataclasses.dataclass
+class FusedActivations:
+    """One flush's sparse activations in the fused multi-table space.
+
+    The host form :func:`shard_block_activations` compiles from, made by
+    :func:`fuse_activations`.  Rows follow :func:`concat_compiled_queries`'s
+    layout: each table's rows start at a ``q_block`` multiple, so blocks
+    never span tables, and ``spans`` are the same.
+
+    Attributes:
+      row / tile / rows: ``(A,)`` fused query row, fused tile id and
+        active-wordline count of each activation, in (row, tile) order.
+      ent_slot: ``(E,)`` wordline slot of each activated entry, in
+        (row, tile, slot) order: activation ``a`` owns ``rows[a]`` of them.
+      num_rows: fused rows, every table padded to a ``q_block`` multiple.
+      tile_rows: wordlines per tile.
+      spans: per-table ``(row_start, batch)``.
+    """
+
+    row: np.ndarray
+    tile: np.ndarray
+    rows: np.ndarray
+    ent_slot: np.ndarray
+    num_rows: int
+    tile_rows: int
+    spans: list
+
+
 def shard_block_queries(
     cq: CompiledQueries,
     plan,
@@ -307,9 +342,87 @@ def shard_block_queries(
     recompiling — or waiting for — the fused global batch.  Every
     sharded-once tile the batch activates must be owned by a
     participant; a query routed to the wrong subset raises.
+
+    The schedule is built by the same core as
+    :func:`shard_block_activations`; this form copies each activation's
+    dense bitmap row (dtype kept) and returns device arrays.
     """
     if q_block < 1:
         raise ValueError("q_block must be >= 1")
+    ids, bms = np.asarray(cq.tile_ids), np.asarray(cq.bitmaps)
+    vq, vs = np.nonzero(ids >= 0)
+    sched, dest = _shard_schedule(
+        vq, ids[vq, vs], ids.shape[0], plan, q_block,
+        max_tiles=max_tiles, participants=participants,
+    )
+    blocked_bms = np.zeros(
+        sched.tile_ids.shape + (q_block, bms.shape[-1]), dtype=bms.dtype
+    )
+    blocked_bms[dest] = bms[vq, vs]
+    return dataclasses.replace(
+        sched,
+        tile_ids=jnp.asarray(sched.tile_ids),
+        bitmaps=jnp.asarray(blocked_bms),
+    )
+
+
+def shard_block_activations(
+    fused: FusedActivations,
+    plan,
+    q_block: int,
+    *,
+    bitmap_dtype,
+    participants: Sequence[int] | None = None,
+) -> ShardedBlockedQueries:
+    """Fused sparse activations → per-shard blocked layout, host-only.
+
+    The flush's compile (DESIGN.md §4.2): the same schedule as
+    :func:`shard_block_queries` of the dense
+    ``concat_compiled_queries([offset_compiled_queries(compile_queries(
+    ...)) ...])`` chain — tile ids, 0/1 bitmaps, widths and shards equal
+    bit for bit, for any plan, participant subset and table mix — but
+    built straight from ``fused`` (:func:`fuse_activations`), with no
+    dense flat bitmap and no device array.  ``tile_ids`` and ``bitmaps``
+    are NumPy, the 0/1 bitmaps of ``bitmap_dtype`` (the kernel layer's
+    choice); the caller puts them on the device once, at dispatch.
+    """
+    if q_block < 1:
+        raise ValueError("q_block must be >= 1")
+    if fused.num_rows % q_block:
+        raise ValueError(
+            f"fused rows {fused.num_rows} are not a q_block={q_block} multiple"
+        )
+    sched, dest = _shard_schedule(
+        fused.row, fused.tile, fused.num_rows, plan, q_block,
+        max_tiles=None, participants=participants,
+    )
+    bitmaps = np.zeros(
+        sched.tile_ids.shape + (q_block, fused.tile_rows),
+        dtype=bitmap_dtype,
+    )
+    a = np.repeat(np.arange(fused.rows.size), fused.rows)  # entry → activation
+    bitmaps[dest[0][a], dest[1][a], dest[2][a], dest[3][a], fused.ent_slot] = 1
+    return dataclasses.replace(sched, bitmaps=bitmaps)
+
+
+def _shard_schedule(
+    row: np.ndarray,
+    tile: np.ndarray,
+    batch: int,
+    plan,
+    q_block: int,
+    *,
+    max_tiles: int | None,
+    participants: Sequence[int] | None,
+) -> tuple[ShardedBlockedQueries, tuple[np.ndarray, ...]]:
+    """The block-key core of both per-shard block compilers.
+
+    Takes each activation's query row and fused tile id, of ``batch``
+    rows padded up to ``q_block`` blocks.  Returns the schedule with
+    NumPy ``tile_ids`` (and no bitmaps yet), plus each activation's
+    bitmap destination ``(stack position, block, slot, row in block)``;
+    the caller fills the bitmaps from its own form of the batch.
+    """
     S = int(plan.num_shards)
     if participants is None:
         parts = np.arange(S, dtype=np.int64)
@@ -322,15 +435,11 @@ def shard_block_queries(
             raise ValueError(f"participants {parts} out of range for {S} shards")
         shards_field = parts
     P = int(parts.size)
-    ids, bms, nb = _pad_to_blocks(
-        np.asarray(cq.tile_ids), np.asarray(cq.bitmaps), q_block
-    )
-    batch = cq.tile_ids.shape[0]
-    tile_rows = bms.shape[-1]
+    nb = -(-batch // q_block)
     nb_safe = max(nb, 1)
 
-    vq, vs = np.nonzero(ids >= 0)
-    vt = ids[vq, vs].astype(np.int64)
+    vq = np.asarray(row, dtype=np.int64)
+    vt = np.asarray(tile, dtype=np.int64)
     vblk = vq // q_block
     shard_of_tile = np.asarray(plan.shard_of_tile)
     own = shard_of_tile[vt].astype(np.int64)
@@ -379,20 +488,17 @@ def shard_block_queries(
     blocked_ids = np.full((P, nb_safe, max_tiles), -1, dtype=np.int32)
     pos_u = segment_ranks(per_sb)
     blocked_ids[us, ub, pos_u] = ult
-    blocked_bms = np.zeros(
-        (P, nb_safe, max_tiles, q_block, tile_rows), dtype=bms.dtype
-    )
     pos_entry = pos_u[np.searchsorted(uniq, key)]
-    blocked_bms[pos_own, vblk, pos_entry, vq % q_block] = bms[vq, vs]
     widths = per_sb.reshape(P, nb_safe).max(axis=1) if uniq.size else np.zeros(P, np.int64)
-    return ShardedBlockedQueries(
-        tile_ids=jnp.asarray(blocked_ids),
-        bitmaps=jnp.asarray(blocked_bms),
+    sched = ShardedBlockedQueries(
+        tile_ids=blocked_ids,
+        bitmaps=None,
         q_block=q_block,
         batch=batch,
         shard_widths=widths.astype(np.int64),
         shards=shards_field,
     )
+    return sched, (pos_own, vblk, pos_entry, vq % q_block)
 
 
 class BlockUnionTracker:
@@ -501,24 +607,59 @@ class BlockUnionTracker:
         return nb * _padded_width(width, None, "pending block")
 
 
+def fuse_activations(
+    tables: Sequence[tuple[ActivationSet, int]], q_block: int
+) -> FusedActivations:
+    """Joins per-table activation sets into one fused flush, host-only.
+
+    ``tables`` holds each served table's
+    :func:`~repro.core.mapping.compile_activations` result (compiled with
+    ``replica_block=q_block``) and its fused tile offset, in flush order.
+    The sparse counterpart of :func:`offset_compiled_queries` +
+    :func:`concat_compiled_queries`: same row layout, same spans, no
+    dense bitmap.
+    """
+    if q_block < 1:
+        raise ValueError("q_block must be >= 1")
+    if not tables:
+        raise ValueError("need at least one activation set")
+    row, tile, rows, slot, spans = [], [], [], [], []
+    start = 0
+    for acts, tile_offset in tables:
+        row.append(acts.act_qid + start)
+        tile.append(acts.act_tile + tile_offset)
+        rows.append(acts.act_rows)
+        slot.append(acts.ent_slot)
+        spans.append((start, acts.batch))
+        start += -(-acts.batch // q_block) * q_block
+    return FusedActivations(
+        row=np.concatenate(row).astype(np.int64),
+        tile=np.concatenate(tile).astype(np.int64),
+        rows=np.concatenate(rows).astype(np.int64),
+        ent_slot=np.concatenate(slot).astype(np.int64),
+        num_rows=start,
+        tile_rows=int(tables[0][0].tile_rows),
+        spans=spans,
+    )
+
+
 def fused_group_loads(
-    cq: CompiledQueries, tile_group: np.ndarray, num_groups: int
+    fused: FusedActivations, tile_group: np.ndarray, num_groups: int
 ) -> np.ndarray:
-    """Per-fused-group active-row counts of a compiled batch.
+    """Per-fused-group active-row counts of a flush.
 
     The serve-time observation feeding drift tracking (DESIGN.md §6):
     instead of re-walking the ragged host queries, the load is read off
-    the batch that was compiled for the kernel anyway.  Each valid
-    (query, tile) slot contributes its wordline popcount to the tile's
-    group, so a query touching *k* rows of a group counts *k* — the same
-    per-row semantics as ``CoOccurrenceGraph.freq`` aggregated by
-    ``Grouping.group_freq``, which is what the shard plan's
+    the activations that were compiled for the kernel anyway.  Each
+    (query, tile) activation contributes its wordline count to the
+    tile's group, so a query touching *k* rows of a group counts *k* —
+    the same per-row semantics as ``CoOccurrenceGraph.freq`` aggregated
+    by ``Grouping.group_freq``, which is what the shard plan's
     ``group_load`` was built from.  Replica choice does not matter: all
     replicas of a group map to the same group id.
 
     Args:
-      cq: a compiled batch in the *fused* tile space (post
-        :func:`offset_compiled_queries` / :func:`concat_compiled_queries`).
+      fused: the flush's :class:`FusedActivations` (fused tile space).
       tile_group: ``(num_tiles,)`` fused tile id → fused group id
         (``repeat(arange(G), group_copies)``).
       num_groups: fused group count G.
@@ -526,14 +667,11 @@ def fused_group_loads(
     Returns:
       ``(G,)`` float64 active-row counts.
     """
-    ids = np.asarray(cq.tile_ids)
-    valid = ids >= 0
-    if not valid.any():
+    if not fused.tile.size:
         return np.zeros(num_groups, dtype=np.float64)
-    groups = np.asarray(tile_group)[ids[valid].astype(np.int64)]
-    rows = np.asarray(cq.bitmaps)[valid].sum(axis=-1)
+    groups = np.asarray(tile_group)[fused.tile]
     return np.bincount(
-        groups, weights=rows.astype(np.float64), minlength=num_groups
+        groups, weights=fused.rows.astype(np.float64), minlength=num_groups
     ).astype(np.float64)
 
 

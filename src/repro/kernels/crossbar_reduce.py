@@ -109,6 +109,13 @@ def _kernel(
         out_ref[...] = acc_ref[...][None].astype(out_ref.dtype)
 
 
+#: dtype of the 0/1 schedule bitmaps a serving flush sends to the device
+#: (``repro.kernels.sharded.put_schedule``): a quarter of f32's transfer.
+#: The kernel widens each bitmap block to f32 in VMEM; Mosaic on v5e
+#: refuses a uint8 → f32 cast, int8 it takes.
+SCHEDULE_BITMAP_DTYPE = jnp.int8
+
+
 def crossbar_reduce_pallas(
     image: jax.Array,     # (num_tiles, tile_rows, dim)
     tile_ids: jax.Array,  # (batch | nb, max_tiles) int32, -1 padding

@@ -47,7 +47,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.crossbar_reduce import crossbar_reduce_pallas
 
@@ -252,8 +252,8 @@ def _chunk_bounds(nb: int, combine_chunks: int) -> list[tuple[int, int]]:
 
 def crossbar_reduce_sharded(
     images: jax.Array,    # (S, local_tiles, tile_rows, dim) stacked shard images
-    tile_ids: jax.Array,  # (P, nb, max_tiles) int32 shard-local ids, -1 pad
-    bitmaps: jax.Array,   # (P, nb, max_tiles, q_block, tile_rows)
+    tile_ids: jax.Array,  # (P | S, nb, max_tiles) int32 shard-local ids, -1 pad
+    bitmaps: jax.Array,   # (P | S, nb, max_tiles, q_block, tile_rows)
     *,
     mesh=None,
     axis_name: str = "model",
@@ -278,10 +278,12 @@ def crossbar_reduce_sharded(
       combine_chunks: block-axis chunks for combine/DMA overlap.
       shard_ids: when the batch was compiled for a shard *subset*
         (``participants=`` — the scheduler's per-shard and owner-set
-        flushes, DESIGN.md §7), the global shard id of each stacked
-        schedule.  Emulation runs only the participating shards'
-        kernels; under shard_map the subset schedules scatter into a
-        full-``S`` stack of empty (all ``-1``) schedules and the
+        flushes, DESIGN.md §7), the global shard id of each participant.
+        Emulation takes the participants' schedules stacked in that
+        order and runs only their kernels.  Under shard_map the
+        schedules always come as the full ``S`` stack, one per device:
+        a subset's scattered into empty (all ``-1``) schedules in global
+        shard order, as :func:`put_schedule` puts them; the
         combine shrinks with the subset: a single participant skips the
         collective entirely, a multi-shard subset whose size divides
         the mesh rings only its participants via grouped psum
@@ -313,6 +315,36 @@ def lower_sharded(images, tile_ids, bitmaps, **kw):
     return fn.lower(*args)
 
 
+def put_schedule(images, tile_ids, bitmaps, *, mesh=None, axis_name="model",
+                 shard_ids=None):
+    """A flush's one host→device transfer of its schedule.
+
+    Puts the NumPy ``tile_ids`` and ``bitmaps`` (from
+    ``shard_block_activations``) in one ``jax.device_put``, where the
+    flush program takes them: on the device of ``images`` without a
+    mesh, and sharded over ``axis_name`` like the images on one, so no
+    flush reshards its schedule.  On a mesh a subset schedule
+    (``shard_ids`` names fewer shards than the mesh) is first scattered
+    on the host into the full stack of empty schedules in global shard
+    order — the only form :func:`crossbar_reduce_sharded` takes there.
+
+    Returns the device ``(tile_ids, bitmaps)``.
+    """
+    S = images.shape[0]
+    if mesh is None:
+        return jax.device_put((tile_ids, bitmaps), images.sharding)
+    if shard_ids is not None and len(shard_ids) < S:
+        part = np.asarray(shard_ids, dtype=np.int64)
+        full_ids = np.full((S,) + tile_ids.shape[1:], -1, dtype=tile_ids.dtype)
+        full_bms = np.zeros((S,) + bitmaps.shape[1:], dtype=bitmaps.dtype)
+        full_ids[part] = tile_ids
+        full_bms[part] = bitmaps
+        tile_ids, bitmaps = full_ids, full_bms
+    return jax.device_put(
+        (tile_ids, bitmaps), NamedSharding(mesh, P(axis_name))
+    )
+
+
 def _select_dispatch(
     images, tile_ids, bitmaps, *, mesh=None, axis_name="model",
     combine="psum_scatter", combine_chunks=1, dynamic_switch=True,
@@ -320,28 +352,29 @@ def _select_dispatch(
 ):
     """Validates a sharded reduction and picks its cached jit program.
 
-    Returns ``(fn, args, take)``: the program, its arguments (subset
-    schedules already scattered into the full mesh stack) and the
+    Returns ``(fn, args, take)``: the program, its arguments and the
     stacked row of its output that holds the result (``None`` when the
     output is the result, as on the emulation path).
     """
     S, _, _, dim = images.shape
     if shard_ids is None:
-        if tile_ids.shape[0] != S or bitmaps.shape[0] != S:
-            raise ValueError(
-                f"shard axes disagree: images {images.shape[0]}, "
-                f"tile_ids {tile_ids.shape[0]}, bitmaps {bitmaps.shape[0]}"
-            )
         part = np.arange(S, dtype=np.int64)
     else:
         part = np.asarray(shard_ids, dtype=np.int64)
-        if tile_ids.shape[0] != part.size or bitmaps.shape[0] != part.size:
-            raise ValueError(
-                f"shard_ids has {part.size} entries, schedules have "
-                f"{tile_ids.shape[0]}/{bitmaps.shape[0]}"
-            )
         if part.size and (part.min() < 0 or part.max() >= S):
             raise ValueError(f"shard_ids {part} out of range for {S} shards")
+    # emulation stacks the participants' schedules only; shard_map takes
+    # one per device, a subset's scattered into empty (-1 / zero)
+    # schedules on the host (put_schedule) — empty grids are masked
+    # in-kernel, so non-participants produce exact-zero partials
+    depth = S if mesh is not None or shard_ids is None else part.size
+    if tile_ids.shape[0] != depth or bitmaps.shape[0] != depth:
+        raise ValueError(
+            f"{depth} stacked schedules expected for images "
+            f"{images.shape[0]} and shard_ids {shard_ids}"
+            f"{' on a mesh' if mesh is not None else ''}, got "
+            f"tile_ids {tile_ids.shape[0]}, bitmaps {bitmaps.shape[0]}"
+        )
     if combine not in ("psum_scatter", "psum"):
         raise ValueError(f"unknown combine {combine!r}")
 
@@ -359,19 +392,6 @@ def _select_dispatch(
         raise ValueError(
             f"mesh axis {axis_name!r} has size {mesh_axis}, need {S} shards"
         )
-    if part.size != S or not np.array_equal(part, np.arange(S)):
-        # shard_map needs one schedule per device: scatter the subset
-        # into empty (-1 / zero) schedules — empty grids are masked
-        # in-kernel, so non-participants produce exact-zero partials.
-        # Device-side functional scatter: no host round-trip of the
-        # just-built schedules on the per-shard flush hot path.
-        idx = jnp.asarray(part, dtype=jnp.int32)
-        tile_ids = jnp.full(
-            (S,) + tuple(tile_ids.shape[1:]), -1, dtype=jnp.int32
-        ).at[idx].set(tile_ids)
-        bitmaps = jnp.zeros(
-            (S,) + tuple(bitmaps.shape[1:]), dtype=bitmaps.dtype
-        ).at[idx].set(bitmaps)
 
     if part.size == 1:
         # single-participant flush: the participant's partial IS the
@@ -428,9 +448,10 @@ def crossbar_reduce_tables(
     """Multi-table entry: one fused sharded reduction, split per table.
 
     ``sbq`` is the fused :class:`~repro.core.reduction.
-    ShardedBlockedQueries` (per-table compiles offset into the fused tile
-    space, concatenated with ``concat_compiled_queries``), ``spans`` the
-    per-table ``(row_start, batch)`` list that call returned.  A subset
+    ShardedBlockedQueries` (per-table compiles in the fused tile space:
+    ``shard_block_activations`` of ``fuse_activations``, or
+    ``shard_block_queries`` of ``concat_compiled_queries``), ``spans``
+    the per-table ``(row_start, batch)`` list of that fusion.  A subset
     compile (``sbq.shards`` set) dispatches only the participating
     shards' kernels — the scheduler's independent per-shard flush path.
 

@@ -25,11 +25,9 @@ undrifted workload starts at drift ≈ 0 and the training prior fades
 with a half-life of ``half_life`` flushes as real observations arrive.
 
 :class:`LoadObservationCache` memoizes the per-batch
-``fused_group_loads`` observation by compiled-batch content: replayed
-streams and steady-state serving re-flush identical compiled batches,
-and the bincount-over-bitmaps observation is several passes over the
-``(batch, max_tiles, tile_rows)`` stack while a content digest is one —
-so the observation cost stops scaling with the flush rate.
+``fused_group_loads`` observation by content: replayed streams and
+steady-state serving re-flush identical batches, whose activations'
+tiles and active-row counts — all the loads depend on — are identical.
 """
 
 from __future__ import annotations
@@ -192,14 +190,12 @@ class DriftTracker:
 class LoadObservationCache:
     """Content-keyed LRU memo for the per-flush load observation.
 
-    Keyed on a BLAKE2b digest of the compiled batch's ``tile_ids`` +
-    ``bitmaps`` bytes (shapes included), NOT on object identity or
-    shape alone: two flushes with the same shape but different queries
-    have different loads, while a replayed/steady-state flush with
-    byte-identical schedules has byte-identical loads.  The digest is a
-    single pass over the stack; a miss additionally runs the real
-    :func:`~repro.core.reduction.fused_group_loads` (boolean indexing +
-    popcount + bincount — several passes plus allocations).
+    Keyed on a BLAKE2b digest of the flush's activation tiles and
+    active-row counts (``FusedActivations.tile`` / ``.rows``), NOT on object identity or shape alone: two flushes of the same shape
+    but different queries have different loads, while a replayed or
+    steady-state flush has byte-identical activations and therefore
+    byte-identical loads.  A miss additionally runs the real
+    :func:`~repro.core.reduction.fused_group_loads`.
 
     Returned arrays are shared with the cache — callers must not
     mutate them (``DriftTracker.observe`` does not).
@@ -212,26 +208,24 @@ class LoadObservationCache:
         self._memo: collections.OrderedDict = collections.OrderedDict()
 
     @staticmethod
-    def _key(cq) -> bytes:
-        ids = np.ascontiguousarray(cq.tile_ids)
-        bms = np.ascontiguousarray(cq.bitmaps)
+    def _key(fused) -> bytes:
         h = hashlib.blake2b(digest_size=16)
-        h.update(repr((ids.shape, str(ids.dtype),
-                       bms.shape, str(bms.dtype))).encode())
-        h.update(ids.tobytes())
-        h.update(bms.tobytes())
+        for a in (fused.tile, fused.rows):
+            a = np.ascontiguousarray(a)
+            h.update(repr((a.shape, str(a.dtype))).encode())
+            h.update(a.tobytes())
         return h.digest()
 
-    def loads(self, cq, tile_group: np.ndarray, num_groups: int) -> np.ndarray:
-        """Memoized ``fused_group_loads(cq, tile_group, num_groups)``."""
-        key = self._key(cq)
+    def loads(self, fused, tile_group: np.ndarray, num_groups: int) -> np.ndarray:
+        """Memoized ``fused_group_loads(fused, tile_group, num_groups)``."""
+        key = self._key(fused)
         hit = self._memo.get(key)
         if hit is not None:
             self.hits += 1
             self._memo.move_to_end(key)
             return hit
         self.misses += 1
-        out = fused_group_loads(cq, tile_group, num_groups)
+        out = fused_group_loads(fused, tile_group, num_groups)
         self._memo[key] = out
         while len(self._memo) > self.maxsize:
             self._memo.popitem(last=False)

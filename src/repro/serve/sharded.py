@@ -117,14 +117,12 @@ from jax.sharding import NamedSharding, PartitionSpec
 from repro.core import (
     build_cooccurrence,
     build_layout,
-    compile_queries,
-    concat_compiled_queries,
+    compile_activations,
     correlation_aware_grouping,
-    offset_compiled_queries,
+    fuse_activations,
     plan_replication,
-    shard_block_queries,
+    shard_block_activations,
 )
-from repro.core.reduction import CompiledQueries
 from repro.dist.replan import (
     PlanPatch,
     apply_plan_patch,
@@ -132,12 +130,14 @@ from repro.dist.replan import (
     rescale_load_to_plan,
 )
 from repro.dist.shard_plan import ShardPlan, build_fused_image, plan_shards
+from repro.kernels.crossbar_reduce import SCHEDULE_BITMAP_DTYPE
 from repro.kernels.sharded import (
     combine_bytes_per_batch,
     crossbar_reduce_tables,
     dispatch_cache_stats,
     lower_sharded,
     patch_shard_images,
+    put_schedule,
 )
 from repro.serve.drift import DriftTracker, LoadObservationCache, ReplanConfig
 from repro.serve.faults import (
@@ -163,7 +163,7 @@ class _InFlight:
     seqs: Dict[str, np.ndarray]            # per-table submission sequence ids
     t0: float                              # host compile start (perf_counter)
     n_queries: int
-    host_cq: object = None                 # host-materialized fused batch
+    fused: object = None                   # the flush's FusedActivations
     # ---- healing metadata (DESIGN.md §8): the raw batch so a retire-
     # time fault can re-dispatch it and a watchdog timeout can degrade
     # it to the host path ----
@@ -222,6 +222,9 @@ class ShardedServeStats:
     host_compile_s: float = 0.0            # Σ per-flush host compile time
     hidden_compile_s: float = 0.0          # … of which overlapped device exec
     in_flight_peak: int = 0                # deepest dispatch queue seen
+    # ---- the schedule's host→device transfer, one put per flush ----
+    schedule_puts: int = 0
+    schedule_bytes: int = 0                # Σ tile-id + bitmap bytes put
     # compile start → retire, one sample per flush
     flush_wall: List[float] = dataclasses.field(default_factory=list)
     submit_wall: List[float] = dataclasses.field(default_factory=list)
@@ -374,6 +377,8 @@ class ShardedServeStats:
             "hidden_compile_s": self.hidden_compile_s,
             "overlap_fraction": self.overlap_fraction,
             "in_flight_peak": self.in_flight_peak,
+            "schedule_puts": self.schedule_puts,
+            "schedule_bytes": self.schedule_bytes,
             "routed": self.routed,
             "route_chunks": self.route_chunks,
             "replans": self.replans,
@@ -847,14 +852,15 @@ class ShardedEmbeddingServer:
         sbq = None
         if served_dev:
             tc = time.perf_counter()
-            host_cq, sbq, spans = self._compile_batch(
+            fused, sbq = self._compile_batch(
                 served_dev, {n: hot_of[n] for n in served_dev}
             )
             # synchronous compile sits squarely on the serving critical
             # path — never hidden (the §7 engine's motivating cost)
             self.stats.record_compile(time.perf_counter() - tc, hidden=False)
             outs = crossbar_reduce_tables(
-                self.shard_images, sbq, spans,
+                self.shard_images, self._put_schedule(sbq, count=True),
+                fused.spans,
                 mesh=self.mesh, axis_name=self.axis_name,
                 combine=self.combine, combine_chunks=self.combine_chunks,
                 dynamic_switch=self.dynamic_switch, interpret=self.interpret,
@@ -863,7 +869,7 @@ class ShardedEmbeddingServer:
             # double buffering: the kernel above is dispatched but NOT
             # yet blocked on — drift bookkeeping and patch computation
             # are pure host work overlapping this flush's device time
-            self._observe_and_stage(host_cq, n_queries)
+            self._observe_and_stage(fused, n_queries)
             outs = [jax.block_until_ready(o) for o in outs]
         elif self.tracker is not None:
             # an all-cold batch still observed loads above — give the
@@ -910,7 +916,8 @@ class ShardedEmbeddingServer:
         a shard subset).  ``.as_text()`` of the result shows whether the
         crossbar kernel is a compiled Mosaic call (``tpu_custom_call``)."""
         served = [n for n in self.names if queries_by_table.get(n)]
-        _, sbq, _ = self._compile_batch(served, queries_by_table, participants)
+        _, sbq = self._compile_batch(served, queries_by_table, participants)
+        sbq = self._put_schedule(sbq, count=False)
         return lower_sharded(
             self.shard_images, sbq.tile_ids, sbq.bitmaps,
             mesh=self.mesh, axis_name=self.axis_name, combine=self.combine,
@@ -922,33 +929,43 @@ class ShardedEmbeddingServer:
     def _compile_batch(self, served, queries_of, participants=None):
         """Fused host compile shared by the sync and async paths.
 
-        Per-table compile (block-granular replica choice) → rebase into
-        the fused tile space → concat (blocks never span tables) → one
-        host materialization serving both the per-shard block compiler
-        and the drift observation (without it, each would pull the
-        batch back from the device separately).
+        Per-table sparse activations (block-granular replica choice) →
+        fused into one tile and row space (blocks never span tables) →
+        the per-shard blocked schedule, built straight from them.  All
+        NumPy: nothing crosses to the device here.  The fused
+        activations also serve the drift observation.
 
-        Returns ``(host_cq, sbq, spans)``.
+        Returns ``(fused, sbq)``, ``sbq`` with NumPy arrays; the per-table
+        spans are ``fused.spans``.
         """
-        cqs = []
+        tables = []
         for name in served:
             i = self.names.index(name)
-            seg = self.plan.tables[i]
-            cq = compile_queries(
+            acts = compile_activations(
                 self.layouts[i], queries_of[name],
                 replica_block=self.q_block,
             )
-            cqs.append(offset_compiled_queries(cq, seg.tile_offset))
-        fused_cq, spans = concat_compiled_queries(cqs, self.q_block)
-        host_cq = CompiledQueries(
-            tile_ids=np.asarray(fused_cq.tile_ids),
-            bitmaps=np.asarray(fused_cq.bitmaps),
-            max_tiles=fused_cq.max_tiles,
+            tables.append((acts, self.plan.tables[i].tile_offset))
+        fused = fuse_activations(tables, self.q_block)
+        sbq = shard_block_activations(
+            fused, self.plan, self.q_block, participants=participants,
+            bitmap_dtype=SCHEDULE_BITMAP_DTYPE,
         )
-        sbq = shard_block_queries(
-            host_cq, self.plan, self.q_block, participants=participants
+        return fused, sbq
+
+    def _put_schedule(self, sbq, *, count: bool):
+        """Returns ``sbq`` with its schedule on the device, put in one
+        transfer where the flush program takes it
+        (:func:`~repro.kernels.sharded.put_schedule`); ``count`` adds the
+        put to ``schedule_puts`` / ``schedule_bytes``."""
+        ids, bms = put_schedule(
+            self.shard_images, sbq.tile_ids, sbq.bitmaps,
+            mesh=self.mesh, axis_name=self.axis_name, shard_ids=sbq.shards,
         )
-        return host_cq, sbq, spans
+        if count:
+            self.stats.schedule_puts += 1
+            self.stats.schedule_bytes += int(ids.nbytes + bms.nbytes)
+        return dataclasses.replace(sbq, tile_ids=ids, bitmaps=bms)
 
     # --------------------------------------------------------- replanning --
 
@@ -1012,7 +1029,7 @@ class ShardedEmbeddingServer:
             # was flushed under the old plan before we got here)
             self.scheduler.rebuild(self.plan)
 
-    def _observe_and_stage(self, fused_cq, n_queries: int) -> None:
+    def _observe_and_stage(self, fused, n_queries: int) -> None:
         """Feeds the tracker and stages a patch when drift crosses.
 
         Host-only work scheduled between kernel dispatch and
@@ -1023,9 +1040,9 @@ class ShardedEmbeddingServer:
         if self.tracker is None:
             return
         # content-keyed memo: steady-state / replayed streams re-flush
-        # byte-identical compiled batches, whose loads are identical too
+        # identical activations, whose loads are identical too
         loads = self._load_obs.loads(
-            fused_cq, self._tile_group, self.plan.num_groups
+            fused, self._tile_group, self.plan.num_groups
         )
         self.stats.load_obs_hits = self._load_obs.hits
         self.stats.load_obs_misses = self._load_obs.misses
@@ -1543,7 +1560,7 @@ class ShardedEmbeddingServer:
         self.stats.record_flush_home(home)
         # drift bookkeeping is pure host work: it overlaps this flush's
         # device execution exactly like the next flush's compile does
-        self._observe_and_stage(entry.host_cq, entry.n_queries)
+        self._observe_and_stage(entry.fused, entry.n_queries)
         while len(self._in_flight) > self.policy.max_in_flight:
             self._retire_oldest()
 
@@ -1592,7 +1609,7 @@ class ShardedEmbeddingServer:
             qs.append(query)
         served = [n for n in self.names if n in by_table]
         with TraceAnnotation("recross.compile", flush=flush):
-            host_cq, sbq, spans = self._compile_batch(
+            fused, sbq = self._compile_batch(
                 served, {n: by_table[n][1] for n in served},
                 participants=participants,
             )
@@ -1604,8 +1621,10 @@ class ShardedEmbeddingServer:
             else None
         )
         with TraceAnnotation("recross.dispatch", flush=flush):
+            # the flush's one host→device transfer: ids and bitmaps
             outs = crossbar_reduce_tables(
-                self.shard_images, sbq, spans,
+                self.shard_images, self._put_schedule(sbq, count=True),
+                fused.spans,
                 mesh=self.mesh, axis_name=self.axis_name,
                 combine=self.combine, combine_chunks=self.combine_chunks,
                 dynamic_switch=self.dynamic_switch,
@@ -1616,7 +1635,7 @@ class ShardedEmbeddingServer:
             seqs={n: np.asarray(by_table[n][0], dtype=np.int64)
                   for n in served},
             t0=t0, n_queries=sum(len(by_table[n][1]) for n in served),
-            host_cq=host_cq,
+            fused=fused,
             t_dispatch=time.perf_counter(), hang_s=hang_s, flush=flush,
         )
 

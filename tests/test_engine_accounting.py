@@ -6,6 +6,7 @@ build's stage timings, and the spans each flush opens on the engine."""
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -403,5 +404,72 @@ def test_a_compile_fault_inside_a_run_is_requeued_and_surfaced():
             np.testing.assert_array_equal(
                 np.asarray(rows[table]),
                 np.asarray(reduce_dense_oracle(TABLES[table], qs)))
+    finally:
+        srv.close()
+
+
+def test_flush_compile_makes_no_transfer(monkeypatch):
+    """The host block compile stays on the host: with device→host pulls
+    disallowed and every way of making a device array made to raise, it
+    returns NumPy arrays only.  (On the CPU the guard alone lets
+    zero-copy pulls and ``jnp.asarray`` through.)"""
+    srv = _server(threaded=False)
+
+    def no_device_array(*args, **kw):
+        raise AssertionError("the host compile made a device array")
+
+    try:
+        bags = {n: list(zipf_queries(ROWS, 11, 5.0, seed=s))
+                for n, s in (("a", 501), ("b", 502))}
+        with monkeypatch.context() as m:
+            for mod, name in ((jax, "device_put"), (jax.numpy, "asarray"),
+                              (jax.numpy, "array")):
+                m.setattr(mod, name, no_device_array)
+            with jax.transfer_guard_device_to_host("disallow"):
+                fused, sbq = srv._compile_batch(["a", "b"], bags)
+                subset = srv._compile_batch(
+                    ["a"], {"a": bags["a"]}, participants=[0, 1])[1]
+        for arr in (sbq.tile_ids, sbq.bitmaps, subset.tile_ids,
+                    subset.bitmaps, fused.tile, fused.rows):
+            assert isinstance(arr, np.ndarray)
+        assert sbq.bitmaps.dtype == np.int8
+        assert fused.spans == [(0, 11), (12, 11)]
+    finally:
+        srv.close()
+
+
+def test_each_flush_puts_its_schedule_once(monkeypatch):
+    """A threaded server puts each flush's schedule in one transfer,
+    counted in ``schedule_puts`` / ``schedule_bytes``, and still serves
+    the reference sums."""
+    import repro.serve.sharded as sharded
+
+    put = []
+    real = sharded.put_schedule
+
+    def counted(images, tile_ids, bitmaps, **kw):
+        out = real(images, tile_ids, bitmaps, **kw)
+        put.append(sum(int(a.nbytes) for a in out))
+        assert bitmaps.dtype == np.int8
+        return out
+
+    monkeypatch.setattr(sharded, "put_schedule", counted)
+    srv = _server()
+    try:
+        bags = {n: list(zipf_queries(ROWS, 40, 5.0, seed=s))
+                for n, s in (("a", 511), ("b", 512))}
+        for i in range(40):
+            for n in ("a", "b"):
+                srv.submit(n, bags[n][i])
+        out = srv.drain()
+        st = srv.stats
+        assert st.batches > 1
+        assert st.schedule_puts == st.batches == len(put)
+        assert st.schedule_bytes == sum(put)
+        assert srv.report()["serve"]["schedule_puts"] == st.batches
+        for n in ("a", "b"):
+            want = np.stack([TABLES[n][np.unique(q)].sum(axis=0)
+                             for q in bags[n]])
+            np.testing.assert_array_equal(np.asarray(out[n]), want)
     finally:
         srv.close()

@@ -12,6 +12,7 @@ and a no-drift serving window stages zero patches.
 """
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 from hypothesis import given, settings, strategies as st
@@ -19,8 +20,10 @@ from hypothesis import given, settings, strategies as st
 from repro.core import (
     build_cooccurrence,
     build_layout,
+    compile_activations,
     compile_queries,
     correlation_aware_grouping,
+    fuse_activations,
     fused_group_loads,
     plan_replication,
     shard_block_queries,
@@ -470,20 +473,35 @@ def test_drift_tracker_statistic():
     assert tr.drift_from(np.zeros(4)) == 0.0
 
 
-def test_fused_group_loads_matches_row_semantics():
+@pytest.mark.parametrize("repeat", [False, True], ids=["sets", "repeated_ids"])
+def test_fused_group_loads_matches_row_semantics(repeat):
+    """Row-semantics loads off the flush's fused activations, bit for bit
+    the popcounts of the dense compiled batch's valid slots."""
     rows = 160
     hist = zipf_queries(rows, 40, 5.0, seed=11)
     layout, plan, gfreq = _pipeline(rows, hist)
     sp = plan_shards([layout], [plan], 2, group_freqs=[gfreq])
     ev = zipf_queries(rows, 12, 5.0, seed=12)
-    cq = compile_queries(layout, ev, replica_block=4)
+    if repeat:
+        ev = [list(q) + list(q[:2]) for q in ev]
+    fused = fuse_activations(
+        [(compile_activations(layout, ev, replica_block=4), 0)], 4)
     tile_group = np.repeat(np.arange(sp.num_groups), sp.group_copies)
-    got = fused_group_loads(cq, tile_group, sp.num_groups)
+    got = fused_group_loads(fused, tile_group, sp.num_groups)
     want = np.zeros(sp.num_groups)
     for q in ev:
         rows_u = np.unique(np.asarray(q, dtype=np.int64))
         np.add.at(want, layout.group_of[rows_u], 1.0)
     np.testing.assert_array_equal(got, want)
+    cq = compile_queries(layout, ev, replica_block=4)
+    ids = np.asarray(cq.tile_ids)
+    valid = ids >= 0
+    dense = np.bincount(
+        tile_group[ids[valid]],
+        weights=np.asarray(cq.bitmaps)[valid].sum(axis=-1).astype(np.float64),
+        minlength=sp.num_groups,
+    )
+    np.testing.assert_array_equal(got, dense)
 
 
 # ----------------------------------------------------- serving driver --

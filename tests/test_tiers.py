@@ -23,8 +23,10 @@ import pytest
 from repro.core import (
     build_cooccurrence,
     build_layout,
+    compile_activations,
     compile_queries,
     correlation_aware_grouping,
+    fuse_activations,
     plan_replication,
     shard_block_queries,
 )
@@ -602,29 +604,34 @@ def test_host_queue_deadline_forces_flush_in_hot_stream():
 # --------------------------------------- observation + dispatch caches --
 
 
-def test_load_observation_cache_is_content_keyed():
+@pytest.mark.parametrize("maxsize", [1, 4])
+def test_load_observation_cache_is_content_keyed(maxsize):
+    """Keyed on the flush's content: a fresh but equal batch hits, other
+    queries miss, and the memo holds at most ``maxsize`` batches."""
     rows = 192
     hist = zipf_queries(rows, 48, 6.0, seed=2)
     layout, plan, gfreq = _pipeline(rows, hist)
     sp = plan_shards([layout], [plan], 2, group_freqs=[gfreq])
     tile_group = np.repeat(np.arange(sp.num_groups), sp.group_copies)
+
+    def batch(ev):
+        return fuse_activations(
+            [(compile_activations(layout, ev, replica_block=4), 0)], 4)
+
     ev1 = zipf_queries(rows, 8, 6.0, seed=3)
     ev2 = zipf_queries(rows, 8, 6.0, seed=4)
-    cq1 = compile_queries(layout, ev1, replica_block=4)
-    cq2 = compile_queries(layout, ev2, replica_block=4)
-    cache = LoadObservationCache(maxsize=4)
-    a = cache.loads(cq1, tile_group, sp.num_groups)
-    b = cache.loads(cq1, tile_group, sp.num_groups)   # identical content
-    c = cache.loads(cq2, tile_group, sp.num_groups)   # different queries
+    cache = LoadObservationCache(maxsize=maxsize)
+    a = cache.loads(batch(ev1), tile_group, sp.num_groups)
+    b = cache.loads(batch(ev1), tile_group, sp.num_groups)  # identical content
+    c = cache.loads(batch(ev2), tile_group, sp.num_groups)  # different queries
     assert cache.hits == 1 and cache.misses == 2
     np.testing.assert_array_equal(a, b)
     assert not np.array_equal(a, c) or ev1 == ev2
     # eviction bound holds
     for seed in range(5, 12):
         ev = zipf_queries(rows, 8, 6.0, seed=seed)
-        cache.loads(compile_queries(layout, ev, replica_block=4),
-                    tile_group, sp.num_groups)
-    assert len(cache._memo) <= 4
+        cache.loads(batch(ev), tile_group, sp.num_groups)
+    assert len(cache._memo) <= maxsize
 
 
 def test_server_memoizes_repeated_flush_observation():

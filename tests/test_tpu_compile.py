@@ -18,9 +18,11 @@ import jax.numpy as jnp
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.crossbar_reduce import crossbar_reduce_pallas
+from repro.kernels.crossbar_reduce import (
+    SCHEDULE_BITMAP_DTYPE, crossbar_reduce_pallas,
+)
 from repro.kernels.sharded import (
-    _emulated_fn, _mesh_fn, _mesh_single_fn, _mesh_subset_fn,
+    _emulated_fn, _mesh_fn, _mesh_single_fn, _mesh_subset_fn, lower_sharded,
 )
 
 TILE_ROWS, DIM, Q_BLOCK = 64, 128, 8
@@ -139,3 +141,49 @@ def test_flush_program_names_its_kernel(one_chip):
     assert len(calls) == 2
     assert all(line.lstrip().startswith("%recross_crossbar_reduce")
                for line in calls)
+
+
+@pytest.mark.parametrize(
+    "shard_ids", [None, (0,), (0, 2), (0, 1, 2, 3)],
+    ids=["one_chip", "mesh_single", "mesh_subset", "mesh_full"],
+)
+def test_lower_flush_takes_one_byte_bitmaps(one_chip, mesh, shard_ids):
+    """What ``lower_flush`` lowers: the schedule as ``put_schedule`` puts
+    it, with int8 bitmaps (a mesh subset already scattered into the full
+    stack), compiled with the kernel in Mosaic and an s8 bitmap input
+    (one shard's schedule per device)."""
+    if shard_ids is None:
+        shards, sharding, kw = 1, one_chip, {}
+    else:
+        shards = mesh.shape["model"]
+        sharding = NamedSharding(mesh, P("model"))
+        kw = {"mesh": mesh, "shard_ids": np.asarray(shard_ids)}
+    args = (
+        jax.ShapeDtypeStruct(
+            (shards, NUM_TILES, TILE_ROWS, DIM), jnp.float32, sharding=sharding
+        ),
+        jax.ShapeDtypeStruct((shards, NB, MAX_TILES), jnp.int32, sharding=sharding),
+        jax.ShapeDtypeStruct(
+            (shards, NB, MAX_TILES, Q_BLOCK, TILE_ROWS), SCHEDULE_BITMAP_DTYPE,
+            sharding=sharding,
+        ),
+    )
+    lowered = lower_sharded(*args, combine_chunks=2, interpret=False, **kw)
+    text = _assert_kernel_compiled(lowered)
+    assert f"s8[1,{NB},{MAX_TILES},{Q_BLOCK},{TILE_ROWS}]" in text
+
+
+def test_a_mesh_takes_only_the_full_schedule_stack(mesh):
+    """On a mesh a subset's schedules come scattered into the full stack
+    (``put_schedule``); a stack of the participants alone is refused."""
+    sharding = NamedSharding(mesh, P("model"))
+    images = jax.ShapeDtypeStruct(
+        (mesh.shape["model"], NUM_TILES, TILE_ROWS, DIM), jnp.float32,
+        sharding=sharding,
+    )
+    ids = jax.ShapeDtypeStruct((2, NB, MAX_TILES), jnp.int32)
+    bms = jax.ShapeDtypeStruct(
+        (2, NB, MAX_TILES, Q_BLOCK, TILE_ROWS), SCHEDULE_BITMAP_DTYPE)
+    with pytest.raises(ValueError, match="on a mesh"):
+        lower_sharded(images, ids, bms, mesh=mesh,
+                      shard_ids=np.asarray([0, 2]), interpret=False)
